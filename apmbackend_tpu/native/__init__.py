@@ -80,6 +80,18 @@ def ensure_built(*, quiet: bool = True, timeout_s: float = 45.0) -> Optional[str
         return result
 
 
+def use_build(build_dir: str) -> None:
+    """Load the native pieces from ``build_dir``, the output of ``make -C
+    native BUILD=<build_dir>``, instead of running ``make`` into native/build.
+    Must come before anything loads a native piece in this process."""
+    build_dir = os.path.abspath(build_dir)
+    with _BUILD_LOCK:
+        loaded = _BUILD_RESULT.get("dir", build_dir)
+        if loaded != build_dir:
+            raise RuntimeError(f"native pieces already loaded from {loaded}")
+        _BUILD_RESULT["dir"] = build_dir
+
+
 def tail_binary_path() -> Optional[str]:
     """Path to the apm_tail binary, building if needed; None if unavailable."""
     build = ensure_built()

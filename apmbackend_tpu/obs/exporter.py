@@ -216,7 +216,7 @@ class TelemetryServer:
         try:
             import os
 
-            from ..utils.profiling import heap_snapshot
+            from ..utils.profiling import NO_DEVICE, heap_snapshot, loaded_jax
 
             # pid + uuid: two captures in the same second (or from two
             # processes sharing a log dir) must not collide on one directory
@@ -225,16 +225,18 @@ class TelemetryServer:
             stamp = f"{time.strftime('%Y%m%d-%H%M%S')}-{os.getpid()}-{uuid.uuid4().hex[:6]}"
             trace_dir = os.path.join(self.profile_dir, f"profile-{self.module}-{stamp}")
             result = {"module": self.module, "ms": ms}
-            try:
-                import jax
-
-                jax.profiler.start_trace(trace_dir)
-                time.sleep(ms / 1000.0)
-                jax.profiler.stop_trace()
-                result["trace_dir"] = trace_dir
-            except Exception as e:  # no device / profiler unavailable: still
-                # return the heap side — diagnostics degrade, never 500
-                result["trace_error"] = repr(e)
+            jax = loaded_jax()
+            if jax is None:
+                result["trace_error"] = NO_DEVICE
+            else:
+                try:
+                    jax.profiler.start_trace(trace_dir)
+                    time.sleep(ms / 1000.0)
+                    jax.profiler.stop_trace()
+                    result["trace_dir"] = trace_dir
+                except Exception as e:  # profiler unavailable: still return
+                    # the heap side — diagnostics degrade, never 500
+                    result["trace_error"] = repr(e)
             result["heap_snapshot"] = heap_snapshot(
                 self.profile_dir, f"{self.module}-profile", logger=self.logger
             )

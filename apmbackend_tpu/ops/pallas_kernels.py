@@ -35,24 +35,17 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .stats import percentile_rank  # single source of the reference index math
-
-try:  # pltpu memory spaces exist only on TPU-enabled builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    _VMEM = None
-
-import numpy as np
 
 # numpy scalars: inlined as literals when traced inside the kernel (a closed-
 # over jnp array would be a captured constant, which pallas_call rejects)
 _SIGN = np.uint32(0x80000000)
 _LOW31 = np.uint32(0x7FFFFFFF)
-_UMAX = np.uint32(0xFFFFFFFF)
+_SMAX = np.int32(0x7FFFFFFF)
 
 
 def _f32_to_ukey(x: jnp.ndarray) -> jnp.ndarray:
@@ -69,6 +62,17 @@ def _ukey_to_f32(u: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.bitcast_convert_type(raw, jnp.float32)
 
 
+def _ukey_to_skey(u: jnp.ndarray) -> jnp.ndarray:
+    """uint32 key -> int32 with the same order (flip the sign bit): the TPU
+    compiler implements no reductions over unsigned integers, so the
+    kernel's min runs on these."""
+    return jax.lax.bitcast_convert_type(u ^ _SIGN, jnp.int32)
+
+
+def _skey_to_ukey(s: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(s, jnp.uint32) ^ _SIGN
+
+
 def _select_kernel(window_ref, ranks_ref, v1_ref, v2_ref, *, n_ranks: int):
     """One row-block: exact values at rank k and k+1 for each requested rank.
 
@@ -76,20 +80,21 @@ def _select_kernel(window_ref, ranks_ref, v1_ref, v2_ref, *, n_ranks: int):
     (1-indexed; any value is safe — rows gate on count outside), outputs
     [BR, n_ranks] f32.
     """
-    ukey = _f32_to_ukey(window_ref[...])  # [BR, N]
+    skey = _ukey_to_skey(_f32_to_ukey(window_ref[...]))  # [BR, N]
     for j in range(n_ranks):
         k = ranks_ref[:, j : j + 1]  # [BR, 1]
         p = jnp.zeros_like(k, dtype=jnp.uint32)
         for b in range(31, -1, -1):
             cand = p | np.uint32(1 << b)
-            cnt = jnp.sum((ukey < cand).astype(jnp.int32), axis=1, keepdims=True)
+            cnt = jnp.sum((skey < _ukey_to_skey(cand)).astype(jnp.int32), axis=1, keepdims=True)
             p = jnp.where(cnt < k, cand, p)
         # p is now the exact ukey of the k-th smallest element
-        le = jnp.sum((ukey <= p).astype(jnp.int32), axis=1, keepdims=True)
-        nxt = jnp.min(jnp.where(ukey > p, ukey, _UMAX), axis=1, keepdims=True)
-        p2 = jnp.where(le >= k + 1, p, nxt)  # duplicates: rank k+1 == rank k
+        ps = _ukey_to_skey(p)
+        le = jnp.sum((skey <= ps).astype(jnp.int32), axis=1, keepdims=True)
+        nxt = jnp.min(jnp.where(skey > ps, skey, _SMAX), axis=1, keepdims=True)
+        p2 = jnp.where(le >= k + 1, ps, nxt)  # duplicates: rank k+1 == rank k
         v1_ref[:, j : j + 1] = _ukey_to_f32(p)
-        v2_ref[:, j : j + 1] = _ukey_to_f32(p2)
+        v2_ref[:, j : j + 1] = _ukey_to_f32(_skey_to_ukey(p2))
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -114,10 +119,7 @@ def select_ranks(
         window = jnp.pad(window, ((0, s_pad), (0, 0)), constant_values=jnp.nan)
         ranks = jnp.pad(ranks, ((0, s_pad), (0, 0)), constant_values=1)
     grid = ((S + s_pad) // block_rows,)
-    if _VMEM is not None and not interpret:
-        mem = {"memory_space": _VMEM}
-    else:
-        mem = {}
+    mem = {} if interpret else {"memory_space": pltpu.VMEM}
     out_shape = [
         jax.ShapeDtypeStruct((S + s_pad, R), jnp.float32),
         jax.ShapeDtypeStruct((S + s_pad, R), jnp.float32),

@@ -28,10 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..ops.ewma import EwmaState
 from ..ops.stats import StatsState
@@ -424,19 +421,41 @@ def make_sharded_step(mesh: Mesh, cfg: EngineConfig):
 
 
 def _make_fused_sharded_step(mesh: Mesh, cfg: EngineConfig, lcfg: EngineConfig):
-    """The FUSED pod executor: one shard_mapped donated dispatch per tick —
-    advance_span -> staggered-rebuild chunk -> ring-free core + ICI rollup ->
-    in-place ring writes, the sharded counterpart of pipeline.make_fused_step's
-    fused-all form. The rebuild chunk offset is shard-local (all shards
-    rotate in lockstep through their row blocks, same schedule as
-    ShardedRebuildScheduler) and runs BEFORE the tick so the chunk pass only
-    ever reads the ring (the XLA:CPU read+write copy hazard). Signature
-    matches make_sharded_step: ``step(state, new_label, params) ->
-    (emission, rollup, new_state)``; ``step.rebuild_integrated`` is True."""
-    sliding_idx = sliding_lag_indices(cfg)
+    """The FUSED pod executor: one shard_mapped donated dispatch per tick
+    (``fused_sharded_program``), with the staggered-rebuild chunk offset
+    rotating on the host. Signature matches make_sharded_step:
+    ``step(state, new_label, params) -> (emission, rollup, new_state)``;
+    ``step.rebuild_integrated`` is True."""
     rebuild = engine_needs_rebuild(cfg)
     chunk, starts = _rebuild_rotation(lcfg) if rebuild else (0, [0])
     rot = {"i": 0}
+    jfused = fused_sharded_program(mesh, cfg, lcfg)
+
+    def step(state, new_label, params):
+        s = starts[rot["i"]]
+        rot["i"] = (rot["i"] + 1) % len(starts)
+        return jfused(state, np.int32(new_label), params, np.int32(s))
+
+    step.rebuild_integrated = rebuild
+    step.kind = "fused"
+    step.rebuild_rot = rot
+    step.rebuild_chunk = chunk
+    step.rebuild_starts = starts
+    return step
+
+
+def fused_sharded_program(mesh: Mesh, cfg: EngineConfig, lcfg: EngineConfig):
+    """``jit(shard_map(...))`` of one fused pod tick — advance_span ->
+    staggered-rebuild chunk -> ring-free core + ICI rollup -> in-place ring
+    writes, the sharded counterpart of pipeline.make_fused_step's fused-all
+    form: ``(state, label, params, rebuild_start) -> (emission, rollup,
+    state)``, state donated. The rebuild chunk offset is shard-local (all
+    shards rotate in lockstep through their row blocks, same schedule as
+    ShardedRebuildScheduler) and runs BEFORE the tick so the chunk pass only
+    ever reads the ring (the XLA:CPU read+write copy hazard)."""
+    sliding_idx = sliding_lag_indices(cfg)
+    rebuild = engine_needs_rebuild(cfg)
+    chunk = _rebuild_rotation(lcfg)[0] if rebuild else 0
 
     def local_fn(state, nl, params, rb_start):
         state = state._replace(stats=dstats.advance_span(state.stats, lcfg.stats, nl))
@@ -463,21 +482,9 @@ def _make_fused_sharded_step(mesh: Mesh, cfg: EngineConfig, lcfg: EngineConfig):
         # advance_span's dynamic-trip loop has no replication rule; the
         # outputs' specs above are authoritative (rollup scalars really are
         # replicated by the psums)
-        check_rep=False,
+        check_vma=False,
     )
-    jfused = jax.jit(mapped, donate_argnums=(0,))
-
-    def step(state, new_label, params):
-        s = starts[rot["i"]]
-        rot["i"] = (rot["i"] + 1) % len(starts)
-        return jfused(state, np.int32(new_label), params, np.int32(s))
-
-    step.rebuild_integrated = rebuild
-    step.kind = "fused"
-    step.rebuild_rot = rot
-    step.rebuild_chunk = chunk
-    step.rebuild_starts = starts
-    return step
+    return jax.jit(mapped, donate_argnums=(0,))
 
 
 def make_sharded_rebuild(mesh: Mesh, cfg: EngineConfig):

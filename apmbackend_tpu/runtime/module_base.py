@@ -383,10 +383,10 @@ class ModuleRuntime:
         """Baseline liveness every module reports: the process is serving,
         its RSS, and whether a JAX device is attached (import-light: jax is
         only queried if something already imported it)."""
-        import sys as _sys
+        from ..utils.profiling import loaded_jax
 
         out = {"ok": True, "rss_mb": round(_rss_mb(), 1), "section": self.section}
-        jax_mod = _sys.modules.get("jax")
+        jax_mod = loaded_jax()
         if jax_mod is not None:
             try:
                 devs = jax_mod.local_devices()

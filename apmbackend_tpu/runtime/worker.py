@@ -265,6 +265,9 @@ class WorkerApp:
         self._overflow_alerted_ticks = 0
 
         # -- the device pipeline ---------------------------------------------
+        from ..utils.compile_cache import use_compile_cache
+
+        logger.info("XLA compile cache: %s", use_compile_cache() or "off")
         self.driver = PipelineDriver(
             config,
             alerts_manager=self.alerts_manager,

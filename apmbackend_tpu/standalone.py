@@ -8,6 +8,7 @@ parity mode is the supervisor + AMQP multi-process layout.
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 from .ingest import parser_main
@@ -52,24 +53,59 @@ class StandalonePipeline:
             rt._on_config_change(new_config)
 
     def replay(self, log_dir: str) -> int:
+        """Feed every log file under ``log_dir`` through the parser, then drain.
+
+        A replay reads far faster than the worker ticks, so after each chunk
+        it waits until the pipeline is idle (``_settle``). A burst is then at
+        most the ticks that one chunk's records open, and each tick emits a
+        FullStat row per key and lag into the worker's ``db_insert``
+        producer, whose pause buffer evicts its oldest lines past its cap
+        (100,000). A 64 KiB chunk of the fixture fleet at chip_smoke.py's
+        density spans at most 32 s of log time: 4 ticks, 65,520 rows at
+        8,190 keys and two lags, under the cap even while the sink takes
+        none (chip_smoke.py fails on any eviction)."""
         from .ingest.replay import ReplayDriver
 
         driver = ReplayDriver(self.parser)
-        fed = driver.feed_dir(log_dir)
+        fed = driver.feed_dir(log_dir, chunk_bytes=1 << 16, between_chunks=self._settle)
         driver.finish()
         self.drain()
         return fed
 
+    def _held_lines(self) -> int:
+        """Lines that paused producers hold back from the broker."""
+        return sum(
+            q.buffer_count()
+            for rt in (self.parser_rt, self.lead, self.sink_rt, self.jmx_rt)
+            for q in rt.qm.queue_map.values()
+            if q.type == "p"
+        )
+
+    def _settle(self, stall_s: float = 60.0) -> None:
+        """Deliver until idle: no consumed queue holds a message, the worker
+        has fed its intake to the device, and no paused producer holds
+        lines. Raises if held lines stay put for ``stall_s``."""
+        deadline = time.monotonic() + stall_s
+        held = None
+        while True:
+            pumped = self.broker.pump()
+            had_intake = self.worker.intake_pending
+            self.worker.drain_intake()  # its ticks may enqueue more lines
+            now_held = self._held_lines()
+            if not (pumped or had_intake or now_held):
+                return
+            if pumped or had_intake or held is None or now_held < held:
+                deadline = time.monotonic() + stall_s
+            elif time.monotonic() > deadline:
+                raise RuntimeError(f"standalone pipeline stalled: {now_held} lines "
+                                   f"held by paused producers for {stall_s:.0f} s")
+            else:
+                time.sleep(0.001)  # the pump thread may be delivering
+            held = now_held
+
     def drain(self) -> None:
         """Pump until quiescent, flush device + sink state (test/replay aid)."""
-        while True:
-            pumped = False
-            while self.broker.pump():
-                pumped = True
-            had_intake = self.worker.intake_pending
-            self.worker.drain_intake()  # ring feeding may enqueue more lines
-            if not pumped and not had_intake:
-                break
+        self._settle()
         self.worker.driver.flush()
         while self.broker.pump():
             pass
@@ -89,14 +125,27 @@ class StandalonePipeline:
         # firing into torn-down state (closed log handlers, removed tmp dirs)
         for rt in (self.jmx_rt, self.parser_rt, self.sink_rt, self.lead):
             rt.stop_timers()
-        for rt in (self.jmx_rt, self.parser_rt, self.sink_rt):
+        self._run_exit_handlers(self.jmx_rt, self.parser_rt)
+        # the parser's exit flush reaches the device before the worker saves
+        # its state, and the worker's last rows reach the sink before it
+        # closes: with the pump thread alone, either could race its save
+        while self.broker.pump():
+            pass
+        self.worker.drain_intake()
+        self.worker.shutdown()
+        while self.broker.pump():
+            pass
+        self._run_exit_handlers(self.sink_rt)
+        self.broker.stop()
+
+    @staticmethod
+    def _run_exit_handlers(*runtimes) -> None:
+        for rt in runtimes:
             for handler in reversed(rt._exit_handlers):
                 try:
                     handler()
                 except Exception as e:
                     rt.logger.error(f"Exit handler error: {e}")
-        self.worker.shutdown()
-        self.broker.stop()
 
 
 def main(argv=None) -> int:

@@ -47,8 +47,15 @@ class MemoryBroker:
         self._queues: Dict[str, _NamedQueue] = {}  # guarded-by: _lock
         self._lock = threading.RLock()
         self._drain_callbacks: List[Callable[[], None]] = []  # guarded-by: _lock
-        self._was_full = False  # guarded-by: _lock
+        # queues that refused a send since the last drain event
+        self._full: set = set()  # guarded-by: _lock
         self._pump_thread: Optional[threading.Thread] = None
+        # one deliverer at a time, whether the pump thread or a caller's
+        # pump(): a consumer callback may push into a single-producer
+        # structure (the worker's intake ring), and a caller whose pump()
+        # finds nothing knows no delivery is still running elsewhere
+        # (StandalonePipeline._settle)
+        self._deliver_lock = threading.RLock()
         self._stop = threading.Event()
         self._work = threading.Event()
         # manual-ack ledger: token -> (queue_name, payload, headers), in
@@ -82,7 +89,7 @@ class MemoryBroker:
         with self._lock:
             q = self._queues[name]
             if len(q.items) >= q.capacity:
-                self._was_full = True
+                self._full.add(name)
                 return False
             q.items.append((payload, headers))
         self._work.set()
@@ -148,6 +155,10 @@ class MemoryBroker:
         runs; manual-ack consumers get it moved to the unacked ledger and a
         token as third callback arg.
         """
+        with self._deliver_lock:
+            return self._pump(max_messages)
+
+    def _pump(self, max_messages: Optional[int]) -> int:
         delivered = 0
         while max_messages is None or delivered < max_messages:
             with self._lock:
@@ -181,11 +192,16 @@ class MemoryBroker:
 
     def _maybe_drain(self) -> None:
         with self._lock:
-            if not self._was_full:
+            # drain = a queue that refused can take sends again. Waiting for
+            # EVERY queue deadlocks one process: a worker paused by its own
+            # refused db_insert producer stops consuming transactions, which
+            # then never drains (seen replaying 8k keys standalone). A retry
+            # into a queue that is still full just refuses again.
+            drained = {n for n in self._full
+                       if len(self._queues[n].items) <= self._queues[n].capacity * self.low_water_ratio}
+            if not drained:
                 return
-            if any(len(q.items) > q.capacity * self.low_water_ratio for q in self._queues.values()):
-                return
-            self._was_full = False
+            self._full -= drained
             callbacks = list(self._drain_callbacks)
         for cb in callbacks:
             cb()

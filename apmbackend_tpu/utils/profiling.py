@@ -35,10 +35,24 @@ from typing import Optional
 _TOP_SITES = 40
 
 
-def _device_memory_stats() -> list:
-    try:
-        import jax
+NO_DEVICE = "no device in this process"
 
+
+def loaded_jax():
+    """The ``jax`` module if this process already imported it, else None.
+
+    Only the device owner (the worker) imports jax. A diagnostic in the
+    parser, sink, JMX or manager process must not be what initializes the
+    TPU there: that process would take the chip from the worker, or fail
+    against it."""
+    return sys.modules.get("jax")
+
+
+def _device_memory_stats():
+    jax = loaded_jax()
+    if jax is None:
+        return NO_DEVICE
+    try:
         out = []
         for d in jax.local_devices():
             stats = d.memory_stats() or {}
@@ -135,9 +149,12 @@ class Profiling:
     def start_profiler_server(self, port: int) -> bool:
         """The live-inspection port (--inspect parity): a JAX/XLA profiler
         server that TensorBoard/perfetto can attach to while the module runs."""
+        jax = loaded_jax()
+        if jax is None:
+            if self.logger:
+                self.logger.warning(f"No profiler server on :{port}: {NO_DEVICE}")
+            return False
         try:
-            import jax
-
             jax.profiler.start_server(port)
             self._server_started = True
             if self.logger:

@@ -14,7 +14,7 @@ import time
 
 import numpy as np
 
-from .common import PER_CHIP_NORTH_STAR, latency_stats_ms, result
+from .common import latency_stats_ms, per_chip_unit, result
 
 EWMA_CHANNELS = [
     {"ALPHA": 0.05, "THRESHOLD": 3.0, "WARMUP": 30, "CHANNEL_ID": -1},
@@ -91,8 +91,7 @@ def run(quick: bool = False, *, capacity: int = 8192, ticks: int = 64, tx_per_ti
     return result(
         "multiwindow_baselining_throughput",
         throughput,
-        "metrics/sec/chip",
-        PER_CHIP_NORTH_STAR,
+        *per_chip_unit(),
         {
             "config": "BASELINE.json configs[4]",
             "device": str(jax.devices()[0]),

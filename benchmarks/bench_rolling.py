@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from .common import PER_CHIP_NORTH_STAR, latency_stats_ms, result
+from .common import latency_stats_ms, per_chip_unit, result
 
 
 def _measure(ticks: int, tx_per_tick: int, services: int, capacity: int, telemetry: bool) -> dict:
@@ -998,8 +998,7 @@ def run(quick: bool = False, *, services: int = 100, ticks: int = 64, tx_per_tic
     return result(
         "rolling_baseline_throughput",
         bare["throughput"],
-        "metrics/sec/chip",
-        PER_CHIP_NORTH_STAR,
+        *per_chip_unit(),
         {
             "config": "BASELINE.json configs[1]",
             "device": str(jax.devices()[0]),

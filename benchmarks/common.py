@@ -10,28 +10,11 @@ host-pipeline benches.
 
 from __future__ import annotations
 
-import os
 import time
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-
-def enable_compile_cache() -> None:
-    """Persistent-compile-cache opt-in for benchmark processes — DISABLED by
-    default since round 6: routing XLA:CPU through the cache's
-    cpu_aot_loader compile path miscompiles buffer donation for fused
-    single-program steps (state corruption reproduced in tests/conftest.py's
-    note; numbers measured over corrupted buffers are worthless). Compiles
-    now happen in each bench's warmup, OUTSIDE the measured windows; set
-    APM_BENCH_JAX_CACHE explicitly to re-enable for experiments."""
-    import jax
-
-    if os.environ.get("APM_BENCH_JAX_CACHE"):
-        jax.config.update(
-            "jax_compilation_cache_dir", os.environ["APM_BENCH_JAX_CACHE"]
-        )
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.4)
 
 PER_CHIP_NORTH_STAR = 125_000.0  # metrics/sec/chip (1M / 8 chips)
 POD_NORTH_STAR = 1_000_000.0  # metrics/sec, whole pod
@@ -39,14 +22,26 @@ REFERENCE_FULLSTAT_RATE = 76.0  # FullStat records/sec in prod (stream_insert_db
 REFERENCE_JMX_HOST_RATE = 2.0 / 60.0  # hosts polled per second (2 hosts / 60 s)
 
 
-def result(metric: str, value: float, unit: str, baseline: float, details: Dict) -> Dict:
+def result(metric: str, value: float, unit: str, baseline: Optional[float], details: Dict) -> Dict:
     return {
         "metric": metric,
         "value": round(float(value), 1),
         "unit": unit,
-        "vs_baseline": round(float(value) / baseline, 3),
+        "vs_baseline": None if baseline is None else round(float(value) / baseline, 3),
         "details": details,
     }
+
+
+def per_chip_unit() -> Tuple[str, Optional[float]]:
+    """(unit, baseline) of a device-throughput bench: per chip against the
+    per-chip north star only when a TPU measured it; otherwise the unit
+    names the device and there is no chip baseline to compare with."""
+    import jax
+
+    platform = jax.devices()[0].platform
+    if platform == "tpu":
+        return "metrics/sec/chip", PER_CHIP_NORTH_STAR
+    return f"metrics/sec on {platform}", None
 
 
 def latency_stats_ms(samples_s: List[float]) -> Dict:

@@ -103,12 +103,6 @@ def _write_trajectory(rows, quick: bool) -> str:
 
 def main(argv=None) -> int:
     from . import REGISTRY
-    from .common import enable_compile_cache
-
-    # entry-point side effect only (never at package import): compiles must
-    # not land inside measured windows, but importing benchmarks.common for
-    # a helper must not rewrite process-global jax config either
-    enable_compile_cache()
 
     ap = argparse.ArgumentParser(prog="benchmarks.run")
     ap.add_argument("--config", choices=sorted(REGISTRY), action="append",
@@ -169,6 +163,12 @@ def main(argv=None) -> int:
         return 0 if ok else 1
 
     names = args.config or sorted(REGISTRY)
+    if args.in_process:
+        # only the process that runs the benches touches the device: the
+        # isolating parent stays off JAX so its children can take the chip
+        from apmbackend_tpu.utils.compile_cache import use_compile_cache
+
+        use_compile_cache()
     failed = 0
     traj_rows = []
     for name in names:

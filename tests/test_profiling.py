@@ -86,3 +86,27 @@ def test_profiler_server_start(tmp_path):
     # jax profiler server may be unavailable in some builds; only assert the
     # call is safe and reports a boolean
     assert ok in (True, False)
+
+
+def test_no_device_outside_the_device_owner(tmp_path):
+    """A process that never imported jax (parser, sink, JMX, manager)
+    answers /profile and heap snapshots without touching JAX: importing it
+    there would initialize the TPU and take the chip from the worker."""
+    import subprocess
+
+    code = f"""
+import json, sys
+from apmbackend_tpu.obs.exporter import TelemetryServer
+from apmbackend_tpu.utils.profiling import NO_DEVICE, heap_snapshot
+exp = TelemetryServer(profile_dir={str(tmp_path)!r}, module="parser")
+status, _, body = exp._handle_profile({{"ms": ["1"]}})
+assert status == 200 and json.loads(body)["trace_error"] == NO_DEVICE, body
+with open(heap_snapshot({str(tmp_path)!r}, "parser")) as fh:
+    assert json.load(fh)["devices"] == NO_DEVICE
+assert "jax" not in sys.modules
+print("NO_JAX_OK")
+"""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root,
+                          capture_output=True, text=True, timeout=120)
+    assert "NO_JAX_OK" in proc.stdout, proc.stdout + proc.stderr

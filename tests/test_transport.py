@@ -124,3 +124,24 @@ def test_pump_max_messages_exact():
     assert broker.pump(max_messages=1) == 1
     assert len(got) == 1
     assert broker.pump() == 2
+
+
+def test_drain_fires_per_refused_queue():
+    """A queue that refused and then drained gets its drain event even while
+    another refused queue stays full: in one process, a worker paused by its
+    own full output queue stops consuming its input queue, so waiting for
+    every queue to drain would deadlock."""
+    broker = MemoryBroker(capacity=4)
+    for name in ("stuck", "out"):
+        broker.assert_queue(name)
+    fired = []
+    broker.on_drain(lambda: fired.append(True))
+    for i in range(5):
+        broker.send("stuck", b"x%d" % i)  # nobody consumes: stays full
+        broker.send("out", b"y%d" % i)
+    got = []
+    broker.consume("out", got.append, "sink")
+    broker.pump()
+    assert len(got) == 4
+    assert fired, "the drained queue's drain event waited on the stuck queue"
+    assert broker.queue_depth("stuck") == 4

@@ -10,9 +10,9 @@ compare the final state against a cache-disabled oracle.
 
 Retested 2026-08 on jax 0.4.37: NOT reproducible — oracle, cold-cache and
 warm-cache runs are bit-identical, and the fused-tick parity suite passes
-cold+warm with the cache on. The cache stays opt-in (APM_TEST_JAX_CACHE /
-APM_BENCH_JAX_CACHE) because its only upside is compile time, but this test
-keeps the question answered on every jax bump: if it starts failing, the
+cold+warm with the cache on. The cache stays off on the CPU backend
+(apmbackend_tpu/utils/compile_cache.py) because its only upside there is
+compile time, but this test keeps the question answered on every jax bump: if it starts failing, the
 miscompile is back — re-quarantine before trusting any cached run.
 """
 
@@ -69,7 +69,6 @@ def _run(cache_dir, tmp_path):
     script = tmp_path / "repro.py"
     script.write_text(_REPRO)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("PYTHONPATH", None)
     out = subprocess.run(
         [sys.executable, str(script), cache_dir],
         capture_output=True, text=True, timeout=300,
