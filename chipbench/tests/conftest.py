@@ -1,0 +1,10 @@
+"""The benchmark's own tests run on the CPU at small sizes, in float32 as
+the chip computes (x64 off). The command itself refuses the CPU."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
