@@ -337,6 +337,8 @@ _DEFAULT_CONFIG: dict = {
     # else scatter-gathers (counters summed, histogram buckets merged
     # before the quantile, spans/decisions deduped) and a dead shard is
     # served from the recorder store with explicit partial/stale marking.
+    # Its /attrib and /trace fold in the manager's own plane and span ring;
+    # disabled, the manager serves those of its own process only.
     "queryPlane": {
         "enabled": True,
         # TTL read-through cache for dashboard-repeated queries; 0 disables

@@ -399,14 +399,22 @@ class TransactionParser:
         from ..obs.trace import get_tracer
 
         self._obs_tracer = get_tracer()
-        # attribution plane (obs/attrib): the parser owns two stages —
-        # the scan itself (mirrors parse_ns at chunk granularity) and the
-        # frame pack. Cached clock references; no-ops when the plane is off.
-        from ..obs.attrib import STAGE_FRAME_PACK, STAGE_PARSER_SCAN, get_attrib
+        # attribution plane (obs/attrib): the parser owns three stages —
+        # the scan itself (mirrors parse_ns at chunk granularity), the
+        # frame pack, and the cache-sweep timer. Cached clock references;
+        # no-ops when the plane is off.
+        from ..obs.attrib import (
+            STAGE_FRAME_PACK,
+            STAGE_PARSER_SCAN,
+            STAGE_PARSER_SWEEP,
+            get_attrib,
+        )
 
         _att = get_attrib()
         self._att_scan = _att.clock(STAGE_PARSER_SCAN)
         self._att_pack = _att.clock(STAGE_FRAME_PACK)
+        self._att_sweep = _att.clock(STAGE_PARSER_SWEEP, counters=("released", "failed"))
+        self._neednum_released = 0  # numberless records handed on at expiry
         # logId -> acctNum (backfill source)
         self.acct_cache = TTLCache(acct_ttl_s, clock=clock)
         # the native ingest fast path (marker pre-filter + field extraction
@@ -456,13 +464,27 @@ class TransactionParser:
                 rec.get("start_ts", ""), rec["end_ts"], rec["elapsed"],
                 rec.get("insert_to_db", False),
             )
+            self._neednum_released += 1
 
     def sweep(self) -> None:
-        self.acct_cache.sweep()
-        self.record_cache.sweep()
-        self.need_num_cache.sweep()
-        if self._frame_buf:
-            self._flush_frames_safe("<sweep>")
+        """The cache-sweep timer's body: expire the three caches (expired
+        numberless records are handed on) and flush buffered frames. One
+        ``parser_sweep`` span; counts the records released and the sweeps
+        that raised."""
+        clk = self._att_sweep
+        released0 = self._neednum_released
+        try:
+            with clk.span():
+                self.acct_cache.sweep()
+                self.record_cache.sweep()
+                self.need_num_cache.sweep()
+                if self._frame_buf:
+                    self._flush_frames_safe("<sweep>")
+        except Exception:
+            clk.count("failed")
+            raise
+        finally:
+            clk.count("released", self._neednum_released - released0)
 
     def drain(self) -> None:
         """End-of-replay: flush numberless records out, drop partials."""
@@ -939,11 +961,10 @@ class TransactionParser:
         c = self.counters
         t0 = time.perf_counter_ns()
         try:
-            return self._read_lines_native(file_path, data)
+            with self._att_scan.span():
+                return self._read_lines_native(file_path, data)
         finally:
-            dt = time.perf_counter_ns() - t0
-            c["parse_ns"] += dt
-            self._att_scan.add_busy(dt * 1e-9)
+            c["parse_ns"] += time.perf_counter_ns() - t0
             if self._frame_buf:
                 self._flush_frames_safe(file_path)
 

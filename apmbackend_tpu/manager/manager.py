@@ -427,13 +427,6 @@ class ManagerApp:
         self._health_streaks = {mod.name: 0 for mod in self.modules}
         if getattr(runtime, "telemetry", None) is not None:
             runtime.telemetry.add_route("/fleet", self._fleet_route)
-            # overrides the exporter's per-process /trace: the manager's view
-            # stitches spans ACROSS children by trace_id (the distributed half
-            # of the trace plane)
-            runtime.telemetry.add_route("/trace", self._trace_route)
-            # likewise /attrib: the fleet-merged stage table + bottleneck
-            # verdict over every child's attribution plane
-            runtime.telemetry.add_route("/attrib", self._attrib_route)
             runtime.telemetry.add_health("fleet", self._fleet_health)
 
         # -- durable telemetry spine (obs/store + recorder + SLO, §8.4) ------
@@ -549,16 +542,20 @@ class ManagerApp:
 
         # -- ISSUE 20: the fleet query plane (the read front door) -----------
         # queryPlane.enabled mounts obs.queryplane over this exporter,
-        # REPLACING the per-process /query /trace /decisions /attrib
-        # mounted above: single-service queries route to the owning shard
-        # via the pinned hash + the owner map re-derived from shard
-        # scrapes; cross-service queries scatter-gather; dead shards are
-        # served from the recorder store with partial/stale marking.
+        # REPLACING the per-process /query /trace /decisions /attrib:
+        # single-service queries route to the owning shard via the pinned
+        # hash + the owner map re-derived from shard scrapes; cross-service
+        # queries scatter-gather; dead shards are served from the recorder
+        # store with partial/stale marking. /attrib and /trace fold in the
+        # manager's own plane and span ring (colocated producers) and stitch
+        # spans across children by trace_id.
         self.queryplane = None
         qp_cfg = config.get("queryPlane", {}) or {}
         if bool(qp_cfg.get("enabled", True)) \
                 and getattr(runtime, "telemetry", None) is not None:
+            from ..obs.attrib import get_attrib
             from ..obs.queryplane import QueryPlane
+            from ..obs.trace import get_tracer
 
             qp_partitions = 0
             if shard_mods:
@@ -584,6 +581,9 @@ class ManagerApp:
                 move_retries=int(qp_cfg.get("moveRetries", 2)),
                 freshness=(self.recorder.freshness
                            if self.recorder is not None else None),
+                local_attrib=lambda: get_attrib().snapshot(),
+                local_spans=lambda trace_id, n: get_tracer().ring.spans(
+                    trace_id=trace_id, n=n),
                 logger=logger,
             )
             for qp_path, qp_fn in self.queryplane.make_routes().items():
@@ -897,84 +897,6 @@ class ManagerApp:
         from ..obs.exporter import PROM_CONTENT_TYPE
 
         return 200, PROM_CONTENT_TYPE, self.scrape_fleet()
-
-    def scrape_traces(self, trace_id: Optional[str] = None, timeout_s: float = 2.0) -> dict:
-        """GET every child's /trace, fold in the manager's own process ring
-        (colocated producers), and stitch spans by trace_id — one
-        cross-module view of each sampled transaction's ingest → queue →
-        feed → tick → emit → alert → sink journey. A down child contributes
-        an error marker instead of failing the stitch."""
-        import json as _json
-        import urllib.parse
-        import urllib.request
-
-        from ..obs.trace import get_tracer
-
-        spans: List[dict] = []
-        children: dict = {}
-        q = f"?trace_id={urllib.parse.quote(trace_id)}" if trace_id else ""
-        for name, url in self._child_metrics_targets():
-            try:
-                with urllib.request.urlopen(f"{url}/trace{q}", timeout=timeout_s) as resp:
-                    body = _json.loads(resp.read().decode("utf-8", "replace"))
-                children[name] = body.get("count", 0)
-                for s in body.get("spans", []):
-                    s.setdefault("module", name)
-                    spans.append(s)
-            except Exception as e:
-                children[name] = f"error: {e!r}"
-        for s in get_tracer().ring.spans(trace_id=trace_id):
-            spans.append(s)
-        traces: dict = {}
-        for s in spans:
-            traces.setdefault(s.get("trace_id"), []).append(s)
-        for tid in traces:
-            traces[tid].sort(key=lambda s: (s.get("start", 0.0), s.get("end", 0.0)))
-        return {
-            "children": children,
-            "trace_count": len(traces),
-            "traces": traces,
-        }
-
-    def _trace_route(self, query):
-        import json as _json
-
-        trace_id = (query.get("trace_id") or [None])[0]
-        body = self.scrape_traces(trace_id)
-        return 200, "application/json", _json.dumps(body, indent=1, default=repr)
-
-    def scrape_attribution(self, timeout_s: float = 2.0) -> dict:
-        """GET every child's /attrib, fold in the manager's own process
-        plane (colocated producers), and merge into one fleet-wide stage
-        table + bottleneck verdict (obs.attrib.merge_snapshots). A down
-        child contributes an error marker instead of failing the merge."""
-        import json as _json
-        import urllib.request
-
-        from ..obs.attrib import get_attrib, merge_snapshots
-
-        snapshots = [get_attrib().snapshot()]
-        children: dict = {}
-        for name, url in self._child_metrics_targets():
-            try:
-                with urllib.request.urlopen(f"{url}/attrib", timeout=timeout_s) as resp:
-                    snap = _json.loads(resp.read().decode("utf-8", "replace"))
-                if not snap.get("module") or snap.get("module") == "apm":
-                    snap["module"] = name
-                snapshots.append(snap)
-                children[name] = "ok"
-            except Exception as e:
-                children[name] = f"error: {e!r}"
-        body = merge_snapshots(snapshots)
-        body["child_status"] = children
-        return body
-
-    def _attrib_route(self, _query):
-        import json as _json
-
-        return 200, "application/json", _json.dumps(
-            self.scrape_attribution(), indent=1, default=repr
-        )
 
     def _fleet_health(self) -> dict:
         """Aggregated child liveness for the manager's own /healthz: process

@@ -38,18 +38,32 @@ for a cadence-dominated fleet, ``parser_scan`` for a parser-bound replay.
 bench_replay/bench_rolling certify both namings under reproducible
 inputs, and bench_rolling's ON-vs-OFF A/B gates accounting overhead <2%.
 
+Intervals on the profiler's clock: :meth:`StageClock.span` adds the busy
+delta exactly as ``add_busy`` does and, in the same block, opens a
+``jax.profiler.TraceAnnotation`` named by the stage, so a profiler trace
+shows each stage's intervals on the host plane beside the device planes.
+Spans sit at per-chunk / per-batch / per-tick / per-timer boundaries,
+never per line or message. jax is used only if this process has already
+imported it; ``obs/`` never imports it, and without it a span only
+accumulates. :data:`SPAN_STAGES` is the vocabulary a trace reader looks
+for. Counters (records, slots, bytes, ...) ride a clock as plain numbers
+in its snapshot.
+
 Kill switch: ``APM_NO_ATTRIB=1`` (or ``configure(enabled=False)``) makes
 :meth:`AttributionPlane.clock` hand out a shared no-op clock — call
-sites keep their single cached reference and pay one dead method call.
+sites keep their single cached reference and pay one dead method call;
+its spans neither accumulate nor annotate.
 Stdlib + numpy-free like the rest of ``obs/``.
 """
 
 from __future__ import annotations
 
+import gc
 import os
+import sys
 import threading
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence
 
 from .registry import MetricsRegistry, Sample, get_registry
 
@@ -69,21 +83,94 @@ STAGE_TICK_REBUILD = "tick_rebuild"
 STAGE_TICK_TX_DRAIN = "tick_tx_drain"
 STAGE_TICK_EMIT = "tick_emit"
 STAGE_SINK_ABSORB = "sink_absorb"
+STAGE_PARSER_SWEEP = "parser_sweep"  # the cache-sweep timer (counters: released, failed)
+STAGE_STATE_SAVE = "state_save"  # resume/checkpoint writes (counter: bytes)
+STAGE_INGEST_DISPATCH = "ingest_dispatch"  # host side of one ingest call (records, slots)
+STAGE_GC = "gc"  # the garbage collector's collections
+
+# the stages whose intervals reach the profiler (StageClock.span call sites);
+# the per-line and per-message stages stay clock-only
+SPAN_STAGES = (
+    STAGE_PARSER_SCAN,
+    STAGE_PARSER_SWEEP,
+    STAGE_WORKER_FEED,
+    STAGE_INGEST_DISPATCH,
+    STAGE_TICK_DISPATCH,
+    STAGE_TICK_REBUILD,
+    STAGE_TICK_TX_DRAIN,
+    STAGE_TICK_EMIT,
+    STAGE_SINK_ABSORB,
+    STAGE_STATE_SAVE,
+    STAGE_GC,
+)
 
 # the implicit candidate: wall no instrumented stage accounts for —
 # waiting for the stream to reach the next tick boundary
 CADENCE = "tick_cadence"
 
 
+_annotation = None  # jax.profiler.TraceAnnotation once jax is loaded here
+
+
+def _annotation_cls():
+    """The profiler's annotation class if this process has imported jax,
+    else None. Never imports jax (the obs import-light rule)."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class _Span:
+    """One busy interval of a clock: ``add_busy(perf_counter delta)`` on
+    exit, inside a profiler annotation named by the stage while a trace
+    is being recorded."""
+
+    __slots__ = ("clock", "t0", "ann")
+
+    def __init__(self, clock: "StageClock"):
+        self.clock = clock
+        self.ann = None
+
+    def __enter__(self) -> "_Span":
+        cls = _annotation_cls()
+        if cls is not None and cls.is_enabled():
+            self.ann = cls(self.clock.stage)
+            self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *_exc) -> bool:
+        self.clock.add_busy(time.perf_counter() - self.t0)
+        if self.ann is not None:
+            self.ann.__exit__(None, None, None)
+        return False
+
+
+class _NullSpan:
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *_exc) -> bool:
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
 class StageClock:
-    """Busy/blocked/idle accumulators for ONE stage.
+    """Busy/blocked/idle accumulators for ONE stage, plus named counters.
 
     Single-writer: the thread that owns the stage adds deltas; plain
     float adds under the GIL, no lock on the hot path. ``enabled`` lets
     call sites skip even the perf_counter pair when the plane is off.
     """
 
-    __slots__ = ("stage", "busy_s", "blocked_s", "idle_s", "events")
+    __slots__ = ("stage", "busy_s", "blocked_s", "idle_s", "events", "counts")
 
     enabled = True
 
@@ -93,11 +180,22 @@ class StageClock:
         self.blocked_s = 0.0
         self.idle_s = 0.0
         self.events = 0
+        self.counts: Dict[str, float] = {}
 
     def add_busy(self, dt: float) -> None:
         if dt > 0.0:
             self.busy_s += dt
             self.events += 1
+
+    def span(self):
+        """``with clock.span():`` — the block's wall time is one busy
+        interval (as ``add_busy``), and a profiler interval named by the
+        stage when a trace is recording."""
+        return _Span(self)
+
+    def count(self, name: str, n: float = 1) -> None:
+        """Add ``n`` to the counter ``name`` (``n=0`` declares it)."""
+        self.counts[name] = self.counts.get(name, 0) + n
 
     def add_blocked(self, dt: float) -> None:
         if dt > 0.0:
@@ -113,7 +211,27 @@ class StageClock:
             "blocked_s": self.blocked_s,
             "idle_s": self.idle_s,
             "events": self.events,
+            **self.counts,
         }
+
+
+class _SharedClock(StageClock):
+    """A clock that several threads write (state saves from the engine's
+    timer, the alerts' and the sink's shutdown): adds under a lock."""
+
+    __slots__ = ("_lock",)
+
+    def __init__(self, stage: str):
+        super().__init__(stage)
+        self._lock = threading.Lock()
+
+    def add_busy(self, dt: float) -> None:
+        with self._lock:
+            super().add_busy(dt)
+
+    def count(self, name: str, n: float = 1) -> None:
+        with self._lock:
+            super().count(name, n)
 
 
 class _NullClock(StageClock):
@@ -126,6 +244,12 @@ class _NullClock(StageClock):
     def add_busy(self, dt: float) -> None:
         pass
 
+    def span(self):
+        return _NULL_SPAN
+
+    def count(self, name: str, n: float = 1) -> None:
+        pass
+
     def add_blocked(self, dt: float) -> None:
         pass
 
@@ -133,7 +257,7 @@ class _NullClock(StageClock):
         pass
 
 
-_NULL_CLOCK = _NullClock("_disabled")
+NULL_CLOCK = _NullClock("_disabled")  # the disabled plane's clock, for call sites with none
 
 
 class Occupancy:
@@ -252,6 +376,7 @@ class AttributionPlane:
         self._occ: Dict[str, Occupancy] = {}  # guarded-by: _lock (creation)
         self._t0 = time.perf_counter()
         self._wall0 = time.time()
+        self._track_gc = False
 
     # -- wiring ---------------------------------------------------------------
     def configure(self, *, module: Optional[str] = None,
@@ -262,16 +387,35 @@ class AttributionPlane:
             self.enabled = bool(enabled)
         return self
 
-    def clock(self, stage: str) -> StageClock:
-        """Create-or-get the stage's clock; the shared no-op when the
-        plane is disabled (call sites cache the reference either way)."""
+    def clock(self, stage: str, counters: Sequence[str] = (),
+              shared: bool = False) -> StageClock:
+        """Create-or-get the stage's clock, with ``counters`` declared at
+        0 so they appear before the first count; ``shared`` for a stage
+        that several threads write. The shared no-op when the plane is
+        disabled (call sites cache the reference either way)."""
         if not self.enabled:
-            return _NULL_CLOCK
+            return NULL_CLOCK
         with self._lock:
             clock = self._clocks.get(stage)
             if clock is None:
-                clock = self._clocks[stage] = StageClock(stage)
+                clock = self._clocks[stage] = (_SharedClock if shared else StageClock)(stage)
+            for name in counters:
+                clock.counts.setdefault(name, 0)
             return clock
+
+    def track_gc(self) -> None:
+        """Time the garbage collector's collections on this plane's ``gc``
+        clock (the process's one ``gc.callbacks`` hook, installed on first
+        use). Kept across :meth:`reset`; nothing is recorded while the
+        plane is off."""
+        if not self.enabled:
+            return
+        with self._lock:
+            if STAGE_GC not in self._clocks:
+                self._clocks[STAGE_GC] = StageClock(STAGE_GC)
+            self._track_gc = True
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
 
     def occupancy(self, resource: str,
                   capacity: Optional[float] = None) -> Occupancy:
@@ -289,6 +433,8 @@ class AttributionPlane:
         """Restart the observation window (bench phase boundaries)."""
         with self._lock:
             self._clocks.clear()
+            if self._track_gc:
+                self._clocks[STAGE_GC] = StageClock(STAGE_GC)
             self._occ.clear()
             self._t0 = time.perf_counter()
             self._wall0 = time.time()
@@ -360,6 +506,12 @@ class AttributionPlane:
                 "apm_stage_events_total", sl, st["events"], "counter",
                 "Busy intervals the stage accumulated",
             ))
+            for name, value in st.items():
+                if name not in _CLOCK_FIELDS:
+                    out.append(Sample(
+                        "apm_stage_counter_total", dict(sl, counter=name), value,
+                        "counter", "Items the stage counted, by counter",
+                    ))
         for resource, oc in self.occupancy_table().items():
             rl = dict(labels, resource=resource)
             out.append(Sample(
@@ -392,6 +544,24 @@ class AttributionPlane:
 # -- the process-global plane -------------------------------------------------
 
 _plane = AttributionPlane()
+_CLOCK_FIELDS = ("busy_s", "blocked_s", "idle_s", "events")
+_SHARES = ("busy_share", "blocked_share")  # added per stage by snapshot()
+_gc_span: List[Optional[_Span]] = [None]  # the open collection (one at a time per process)
+
+
+def _on_gc(phase: str, _info: dict) -> None:
+    """``gc.callbacks`` hook: one span per collection, opened on ``start``
+    and closed on ``stop`` on the collecting thread. Takes no lock: a
+    collection can start while this thread holds the plane's lock."""
+    if phase == "start":
+        plane = _plane
+        clock = plane._clocks.get(STAGE_GC) if plane.enabled else None
+        if clock is not None:
+            _gc_span[0] = clock.span().__enter__()
+    else:
+        span, _gc_span[0] = _gc_span[0], None
+        if span is not None:
+            span.__exit__(None, None, None)
 
 
 def get_attrib() -> AttributionPlane:
@@ -436,6 +606,10 @@ def merge_snapshots(snapshots: List[dict]) -> dict:
             agg["blocked_s"] += float(st.get("blocked_s", 0.0))
             agg["idle_s"] += float(st.get("idle_s", 0.0))
             agg["events"] += int(st.get("events", 0))
+            for name, value in st.items():  # the clock's counters
+                if name not in _CLOCK_FIELDS and name not in _SHARES \
+                        and isinstance(value, (int, float)):
+                    agg[name] = agg.get(name, 0) + value
         for resource, oc in (snap.get("occupancy") or {}).items():
             occupancy[f"{snap.get('module', '?')}:{resource}"] = oc
     body = {

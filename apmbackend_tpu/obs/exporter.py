@@ -22,11 +22,11 @@ exposed as :attr:`TelemetryServer.port`). Routes:
   the same process — gets 409 instead of racing two traces.
 - ``GET /trace[?trace_id=&n=]`` — recent spans from the process trace
   ring (obs.trace), JSON; the per-module half of distributed traces (the
-  manager's own ``/trace`` stitches across children by trace_id).
+  manager's query-plane ``/trace`` stitches across children by trace_id).
 - ``GET /attrib`` — the wall-clock attribution plane (obs.attrib): the
   per-stage busy/blocked/idle table, time-weighted occupancies, and the
-  critical-path bottleneck verdict (the manager's ``/attrib`` merges
-  across children).
+  critical-path bottleneck verdict (the manager's query-plane ``/attrib``
+  merges across children).
 - ``GET /decisions[?trace_id=&n=]`` — recent alert decision records
   (obs.decisions): why each page fired, resolvable by trace_id.
 - ``GET /flight?reason=...`` — on-demand flight-recorder bundle when the

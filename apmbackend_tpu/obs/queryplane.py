@@ -257,6 +257,10 @@ class QueryPlane:
     ``store`` is the durable fallback (None -> dead shards stay dead);
     ``freshness`` optionally maps target name -> unixtime of its last
     successful recorder scrape (staleness honesty for degraded serves).
+    ``local_attrib()`` (an /attrib snapshot) and ``local_spans(trace_id,
+    n)`` (span dicts) fold the hosting process's own plane and span ring
+    into /attrib and /trace, beside the shards (the manager's colocated
+    producers).
     """
 
     def __init__(
@@ -273,9 +277,13 @@ class QueryPlane:
         timeout_s: float = 2.0,
         move_retries: int = 2,
         freshness: Optional[Callable[[], Dict[str, float]]] = None,
+        local_attrib: Optional[Callable[[], dict]] = None,
+        local_spans: Optional[Callable[[Optional[str], int], List[dict]]] = None,
         logger=None,
     ):
         self.targets = targets
+        self.local_attrib = local_attrib
+        self.local_spans = local_spans
         self.owners = owners
         self.store = store
         self.partitions = int(partitions)
@@ -581,11 +589,22 @@ class QueryPlane:
         except ValueError:
             raise _BadRequest("bad n parameter")
 
+        children: Dict[str, object] = {}  # rows per live shard, or its error
+
         def live(shard_name, url):
             qs = urllib.parse.urlencode(
                 {"n": n, **({"trace_id": trace_id} if trace_id else {})})
-            doc = self._fetch_json(f"{url}{path}?{qs}")
-            return [r for r in doc.get(field, []) if isinstance(r, dict)]
+            try:
+                doc = self._fetch_json(f"{url}{path}?{qs}")
+            except Exception as e:
+                children[shard_name] = f"error: {e!r}"
+                raise
+            rows = [r for r in doc.get(field, []) if isinstance(r, dict)]
+            if kind == "spans":
+                for r in rows:
+                    r.setdefault("module", shard_name)
+            children[shard_name] = len(rows)
+            return rows
 
         def fallback(shard_name):
             match = {"module": shard_name}
@@ -596,8 +615,11 @@ class QueryPlane:
 
         docs, shard_status = self._fan(list(self.targets() or []),
                                        live, fallback)
-        rows = _dedup_rows([r for _n, doc in docs if doc for r in doc], keys)
-        return {
+        rows = [r for _n, doc in docs if doc for r in doc]
+        if kind == "spans" and self.local_spans is not None:
+            rows.extend(self.local_spans(trace_id, n))
+        rows = _dedup_rows(rows, keys)
+        body = {
             "fleet": True, "count": len(rows), field: rows,
             "shards": shard_status,
             "partial": any(v["status"] != "live"
@@ -605,6 +627,18 @@ class QueryPlane:
             "stale": any(v["status"] == "stale"
                          for v in shard_status.values()),
         }
+        if kind == "spans":
+            # stitched: each sampled transaction's spans across modules,
+            # ordered along its journey
+            body["children"] = children
+            traces: Dict[object, List[dict]] = {}
+            for r in rows:
+                traces.setdefault(r.get("trace_id"), []).append(r)
+            for spans in traces.values():
+                spans.sort(key=lambda r: (r.get("start", 0.0), r.get("end", 0.0)))
+            body["traces"] = traces
+            body["trace_count"] = len(traces)
+        return body
 
     # -- /attrib --------------------------------------------------------------
     def _attrib_from_store(self, shard_name: str, now: float) -> Optional[dict]:
@@ -642,15 +676,29 @@ class QueryPlane:
     def _serve_attrib(self, q: dict, now: float) -> dict:
         from .attrib import merge_snapshots
 
+        child_status: Dict[str, str] = {}
+
         def live(shard_name, url):
-            return self._fetch_json(f"{url}/attrib")
+            try:
+                snap = self._fetch_json(f"{url}/attrib")
+            except Exception as e:
+                child_status[shard_name] = f"error: {e!r}"
+                raise
+            if snap.get("module") in (None, "", "apm"):
+                snap["module"] = shard_name
+            child_status[shard_name] = "ok"
+            return snap
 
         def fallback(shard_name):
             return self._attrib_from_store(shard_name, now)
 
         docs, shard_status = self._fan(list(self.targets() or []),
                                        live, fallback)
-        body = merge_snapshots([d for _n, d in docs if d])
+        snaps = [d for _n, d in docs if d]
+        if self.local_attrib is not None:
+            snaps.insert(0, self.local_attrib())
+        body = merge_snapshots(snaps)
+        body["child_status"] = child_status
         body["shards"] = shard_status
         body["partial"] = any(v["status"] != "live"
                               for v in shard_status.values())
@@ -672,7 +720,7 @@ class QueryPlane:
                 items["start"] = f"{float(items['end']) - 300.0:.3f}"
         return (route,) + tuple(sorted(items.items()))
 
-    def _wrap(self, route: str, serve):
+    def _wrap(self, route: str, serve, cache: bool = True):
         def handler(query):
             q = {k: (v[0] if isinstance(v, list) else v)
                  for k, v in query.items()}
@@ -683,7 +731,7 @@ class QueryPlane:
                 self._m_requests[route].inc()
             now = time.time()
             try:
-                if q.get("cache") == "0" or self._cache.ttl_s <= 0:
+                if not cache or q.get("cache") == "0" or self._cache.ttl_s <= 0:
                     body, hit = serve(q, now), False
                 else:
                     body, hit = self._cache.get_or_compute(
@@ -736,7 +784,9 @@ class QueryPlane:
             "/decisions": self._wrap(
                 "decisions",
                 lambda q, now: self._serve_ring(q, "decisions", now)),
-            "/attrib": self._wrap("attrib", self._serve_attrib),
+            # uncached: the body is the window's live stage table and
+            # each child's liveness at this moment
+            "/attrib": self._wrap("attrib", self._serve_attrib, cache=False),
         }
 
     # -- introspection --------------------------------------------------------

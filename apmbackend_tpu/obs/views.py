@@ -84,7 +84,8 @@ def register_memory_broker(broker, registry: Optional[MetricsRegistry] = None) -
 
 def register_attribution(module: Optional[str], registry: Optional[MetricsRegistry] = None) -> None:
     """Export the process attribution plane (obs.attrib) into the registry:
-    ``apm_stage_{busy,blocked,idle}_seconds_total`` + occupancy gauges.
+    ``apm_stage_{busy,blocked,idle}_seconds_total`` + occupancy gauges,
+    and time the garbage collector on its ``gc`` clock.
     Idempotent inside the plane itself (one collector per registry) — the
     standalone four-runtimes-one-registry topology registers once. A None
     ``module`` installs without claiming the label (non-exporter runtimes,
@@ -95,6 +96,7 @@ def register_attribution(module: Optional[str], registry: Optional[MetricsRegist
     if module is not None:
         plane.configure(module=module)
     plane.install(registry)
+    plane.track_gc()
 
 
 def register_parser(parser, module: str, registry: Optional[MetricsRegistry] = None) -> None:

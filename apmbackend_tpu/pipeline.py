@@ -811,37 +811,19 @@ def make_staged_executor(cfg: EngineConfig, *, core):
     NB = cfg.stats.num_buckets
     advance = jax.jit(dstats.advance_one, static_argnums=1, donate_argnums=(0,))
     evict, write = staged_ring_programs()
-    # APM_STAGE_TIMING=1: accumulate per-stage wall time on step.stage_ms
-    # (diagnostic; each stage then pays a block_until_ready sync)
-    timing = os.environ.get("APM_STAGE_TIMING") == "1"
-    stage_ms = {"advance": 0.0, "evict": 0.0, "core": 0.0, "write": 0.0, "n": 0}
-
-    def _sync(x):
-        jax.block_until_ready(x)
-        return time.perf_counter()
 
     def step(state, new_label, params):
-        t0 = time.perf_counter() if timing else 0.0
         latest = int(state.stats.latest_bucket)
         nl = int(new_label)
         st = state.stats
         for lbl in range(max(latest + 1, nl - NB + 1), nl + 1):
             st = advance(st, cfg.stats, lbl)
         state = state._replace(stats=st)
-        if timing:
-            t1 = _sync(state.stats.counts)
-            stage_ms["advance"] += (t1 - t0) * 1000
 
         rings = tuple(state.zscores[i].values for i in sliding_idx)
         cursors = tuple(state.zscores[i].pos for i in sliding_idx)
         evicted = evict(rings, cursors) if sliding_idx else ()
-        if timing:
-            t2 = _sync(evicted)
-            stage_ms["evict"] += (t2 - t1) * 1000
         *outs, state2, pushes = core(state, nl, params, evicted)
-        if timing:
-            t3 = _sync(pushes)
-            stage_ms["core"] += (t3 - t2) * 1000
         if sliding_idx:
             rings2 = tuple(state2.zscores[i].values for i in sliding_idx)
             new_cursors = tuple(state2.zscores[i].pos for i in sliding_idx)
@@ -850,13 +832,8 @@ def make_staged_executor(cfg: EngineConfig, *, core):
             for i, ring in zip(sliding_idx, new_rings):
                 zs[i] = zs[i]._replace(values=ring)
             state2 = state2._replace(zscores=tuple(zs))
-        if timing:
-            t4 = _sync(state2.zscores[sliding_idx[0]].values if sliding_idx else 0)
-            stage_ms["write"] += (t4 - t3) * 1000
-            stage_ms["n"] += 1
         return (*outs, state2)
 
-    step.stage_ms = stage_ms
     step.rebuild_integrated = False
     step.kind = "staged"
     return step
@@ -1398,10 +1375,10 @@ class PipelineDriver:
                 "Records per ingest scatter",
                 buckets=DEFAULT_COUNT_BUCKETS,
             )
-            # wall-clock attribution (obs.attrib): the tick stage splits the
-            # TickTracer already measures double as busy seconds for the
-            # bottleneck estimator — same perf_counter boundaries, zero new
-            # syncs
+            # wall-clock attribution (obs.attrib): each tick stage the
+            # TickTracer splits is a span of its stage clock (busy seconds
+            # for the bottleneck estimator, an interval in a profiler
+            # trace) — the same boundaries, zero new syncs
             from .obs.attrib import (
                 STAGE_TICK_DISPATCH,
                 STAGE_TICK_EMIT,
@@ -1418,10 +1395,18 @@ class PipelineDriver:
                 "emit": _att.clock(STAGE_TICK_EMIT),
             }
         else:
+            from .obs.attrib import NULL_CLOCK
+
             self._tracer = None
             self._trace = None
             self._decisions = None
-            self._att_tick = None
+            self._att_tick = dict.fromkeys(("dispatch", "rebuild", "tx_drain", "emit"), NULL_CLOCK)
+        # the host side of each ingest dispatch and each state save, with
+        # what they moved (records and padded slots; bytes written)
+        from .obs.attrib import STAGE_INGEST_DISPATCH, STAGE_STATE_SAVE, get_attrib
+
+        self._att_ingest = get_attrib().clock(STAGE_INGEST_DISPATCH, counters=("records", "slots"))
+        self._att_save = get_attrib().clock(STAGE_STATE_SAVE, counters=("bytes",), shared=True)
         self._refresh_params()
         # emission pipelining (tpuEngine.asyncEmission / the async_emission
         # kwarg; default OFF): hold each tick's TickEmission and fetch it
@@ -1915,18 +1900,22 @@ class PipelineDriver:
         dtype = self._np_dtype()
         if self._delta_track:
             self._mark_cells(rows, labels)
+        clk = self._att_ingest
         for i in range(0, len(rows), B):
             m = min(B, len(rows) - i)
             pad = small if m <= small else B
-            r = np.zeros(pad, np.int32)
-            l = np.zeros(pad, np.int32)
-            e = np.zeros(pad, dtype)
-            v = np.zeros(pad, bool)
-            r[:m] = rows[i : i + m]
-            l[:m] = labels[i : i + m]
-            e[:m] = elaps[i : i + m]
-            v[:m] = True
-            self.state = self._ingest(self.state, self.cfg, r, l, e, v)
+            with clk.span():
+                r = np.zeros(pad, np.int32)
+                l = np.zeros(pad, np.int32)
+                e = np.zeros(pad, dtype)
+                v = np.zeros(pad, bool)
+                r[:m] = rows[i : i + m]
+                l[:m] = labels[i : i + m]
+                e[:m] = elaps[i : i + m]
+                v[:m] = True
+                self.state = self._ingest(self.state, self.cfg, r, l, e, v)
+            clk.count("records", m)
+            clk.count("slots", pad)
             if self._tracer is not None:
                 self._m_tx.inc(m)
                 self._m_pending_batch.observe(m)
@@ -1957,19 +1946,23 @@ class PipelineDriver:
         n = len(self._pending)
         small = min(256, self.micro_batch_size)
         pad = small if n <= small else max(self.micro_batch_size, n)
-        rows = np.zeros(pad, np.int32)
-        labels = np.zeros(pad, np.int32)
-        elaps = np.zeros(pad, self._np_dtype())
-        valid = np.zeros(pad, bool)
-        r_t, l_t, e_t = zip(*self._pending)  # column fill, no per-tuple loop
-        rows[:n] = r_t
-        labels[:n] = l_t
-        elaps[:n] = e_t
-        valid[:n] = True
-        if self._delta_track:
-            self._mark_cells(rows[:n], labels[:n])
-        self._pending.clear()
-        self.state = ingest(self.state, self.cfg, rows, labels, elaps, valid)
+        clk = self._att_ingest
+        with clk.span():
+            rows = np.zeros(pad, np.int32)
+            labels = np.zeros(pad, np.int32)
+            elaps = np.zeros(pad, self._np_dtype())
+            valid = np.zeros(pad, bool)
+            r_t, l_t, e_t = zip(*self._pending)  # column fill, no per-tuple loop
+            rows[:n] = r_t
+            labels[:n] = l_t
+            elaps[:n] = e_t
+            valid[:n] = True
+            if self._delta_track:
+                self._mark_cells(rows[:n], labels[:n])
+            self._pending.clear()
+            self.state = ingest(self.state, self.cfg, rows, labels, elaps, valid)
+        clk.count("records", n)
+        clk.count("slots", pad)
         if self._tracer is not None:
             self._m_tx.inc(n)
             self._m_pending_batch.observe(n)
@@ -1985,6 +1978,7 @@ class PipelineDriver:
             # sequence alone — no per-tick readback
             self._delta_ticks.append(int(new_label))
         tr = self._tracer
+        clk = self._att_tick
         # trace plane: a tick with live sampled traces notes its wall window
         # so their "tick" span describes the tick that closed their bucket
         # (looked up by label at emission — exact under async-emission too).
@@ -1997,72 +1991,72 @@ class PipelineDriver:
             # >1 = replay/backfill jump — the megatick-candidate signal)
             catchup = new_label - self._latest_label if self._latest_label else 1
             t0 = time.perf_counter()
-        if self.registry.count != self._params_registry_count:
-            # newly registered services activate (z-score warm-up starts) at
-            # the next tick boundary — the reference's per-key list creation
-            self._refresh_params()
-        emission, self.state = self._step(self.state, new_label, self.params)
+        with clk["dispatch"].span():
+            if self.registry.count != self._params_registry_count:
+                # newly registered services activate (z-score warm-up starts) at
+                # the next tick boundary — the reference's per-key list creation
+                self._refresh_params()
+            emission, self.state = self._step(self.state, new_label, self.params)
         t1 = time.perf_counter() if tr is not None else 0.0
-        if self._rebuild_sched is not None:
-            # staggered exact rebuild of the sliding z-score aggregates: one
-            # row chunk per tick on a rotating schedule (RebuildScheduler) —
-            # the staged executor's companion; the fused executor folds the
-            # chunk into the tick program instead (rebuild_integrated).
-            self.state = self._rebuild_sched.step(self.state)
+        with clk["rebuild"].span():
+            if self._rebuild_sched is not None:
+                # staggered exact rebuild of the sliding z-score aggregates: one
+                # row chunk per tick on a rotating schedule (RebuildScheduler) —
+                # the staged executor's companion; the fused executor folds the
+                # chunk into the tick program instead (rebuild_integrated).
+                self.state = self._rebuild_sched.step(self.state)
         t2 = time.perf_counter() if tr is not None else 0.0
-        edge_ts = dstats.edge_ts_ms(new_label, self.cfg.stats)
+        with clk["tx_drain"].span():
+            edge_ts = dstats.edge_ts_ms(new_label, self.cfg.stats)
 
-        # ordered tx drain to DB (heap pop up to edge timestamp)
-        if self.on_ordered_tx is not None:
-            for tx in self.heap.pop_all_leq(edge_ts):
-                self.on_ordered_tx(tx)
-        else:
-            self.heap.pop_all_leq(edge_ts)
-        # fast-path drain: due raw lines, end_ts-sorted (stable: arrival order
-        # within equal timestamps), one C-speed sort per tick instead of
-        # per-entry heap pushes
-        if self.on_ordered_csv is not None and self._tx_backlog:
-            due = [p for p in self._tx_backlog if p[0] <= edge_ts]
-            if due:
-                self._tx_backlog = [p for p in self._tx_backlog if p[0] > edge_ts]
-                due.sort(key=lambda p: p[0])
-                for _ts, line in due:
-                    self.on_ordered_csv(line)
+            # ordered tx drain to DB (heap pop up to edge timestamp)
+            if self.on_ordered_tx is not None:
+                for tx in self.heap.pop_all_leq(edge_ts):
+                    self.on_ordered_tx(tx)
+            else:
+                self.heap.pop_all_leq(edge_ts)
+            # fast-path drain: due raw lines, end_ts-sorted (stable: arrival order
+            # within equal timestamps), one C-speed sort per tick instead of
+            # per-entry heap pushes
+            if self.on_ordered_csv is not None and self._tx_backlog:
+                due = [p for p in self._tx_backlog if p[0] <= edge_ts]
+                if due:
+                    self._tx_backlog = [p for p in self._tx_backlog if p[0] > edge_ts]
+                    due.sort(key=lambda p: p[0])
+                    for _ts, line in due:
+                        self.on_ordered_csv(line)
 
         t3 = time.perf_counter() if tr is not None else 0.0
-        if trace_tick:
-            self._tick_walls[new_label] = (tick_wall_start, time.time())
-            if len(self._tick_walls) > 8:  # bounded: emission pops its label;
-                # a zero-row emission (count==0) leaves one behind — prune
-                for stale in sorted(self._tick_walls)[:-8]:
-                    self._tick_walls.pop(stale, None)
-        if self._async_emission:
-            # double-buffered readback: hold this tick's emission; deliver
-            # the PREVIOUS one now, while this tick's programs are still in
-            # flight on the device. Per-tick callback order (stats ->
-            # fullstats -> alerts) is preserved; the ordered-tx drain above
-            # stays immediate (host-only bookkeeping, different queue).
-            # Registry count snapshots at dispatch: rows registered later
-            # did not exist at this tick and must not emit for it.
-            prev, self._pending_emission = (
-                self._pending_emission,
-                (new_label, emission, self.registry.count),
-            )
-            if prev is not None:
-                self._process_emission(*prev)
-        else:
-            self._process_emission(new_label, emission, self.registry.count)
+        with clk["emit"].span():
+            if trace_tick:
+                self._tick_walls[new_label] = (tick_wall_start, time.time())
+                if len(self._tick_walls) > 8:  # bounded: emission pops its label;
+                    # a zero-row emission (count==0) leaves one behind — prune
+                    for stale in sorted(self._tick_walls)[:-8]:
+                        self._tick_walls.pop(stale, None)
+            if self._async_emission:
+                # double-buffered readback: hold this tick's emission; deliver
+                # the PREVIOUS one now, while this tick's programs are still in
+                # flight on the device. Per-tick callback order (stats ->
+                # fullstats -> alerts) is preserved; the ordered-tx drain above
+                # stays immediate (host-only bookkeeping, different queue).
+                # Registry count snapshots at dispatch: rows registered later
+                # did not exist at this tick and must not emit for it.
+                prev, self._pending_emission = (
+                    self._pending_emission,
+                    (new_label, emission, self.registry.count),
+                )
+                if prev is not None:
+                    self._process_emission(*prev)
+            else:
+                self._process_emission(new_label, emission, self.registry.count)
         if tr is not None:
-            stages = {
+            tr.record(new_label, {
                 "dispatch": t1 - t0,
                 "rebuild": t2 - t1,
                 "tx_drain": t3 - t2,
                 "emit": time.perf_counter() - t3,
-            }
-            tr.record(new_label, stages, catchup_labels=catchup)
-            if self._att_tick is not None:
-                for k, clk in self._att_tick.items():
-                    clk.add_busy(stages[k])
+            }, catchup_labels=catchup)
 
     # apm: sync-boundary: THE emit readback — the one blocking sync per tick the cost model budgets for (async emission overlaps it with the next dispatch)
     def _process_emission(self, new_label: int, emission: TickEmission, count: int) -> None:
@@ -2415,18 +2409,20 @@ class PipelineDriver:
         # must reach its consumers now or a restore would silently drop it
         self.drain_emission()
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-        arrays = self._capture_resume_arrays(delivery)
         import tempfile
 
-        fd, tmp = tempfile.mkstemp(dir=os.path.dirname(os.path.abspath(path)), suffix=".tmp")
-        try:
-            with os.fdopen(fd, "wb") as fh:
-                np.savez_compressed(fh, **arrays)
-            os.replace(tmp, path)
-        except BaseException:
-            if os.path.exists(tmp):
-                os.unlink(tmp)
-            raise
+        with self._att_save.span():
+            arrays = self._capture_resume_arrays(delivery)
+            fd, tmp = tempfile.mkstemp(dir=os.path.dirname(os.path.abspath(path)), suffix=".tmp")
+            try:
+                with os.fdopen(fd, "wb") as fh:
+                    np.savez_compressed(fh, **arrays)
+                    self._att_save.count("bytes", fh.tell())
+                os.replace(tmp, path)
+            except BaseException:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
+                raise
 
     # -- incremental delta checkpoints (deltachain.py) -----------------------
     def enable_delta_capture(self) -> None:
@@ -2611,8 +2607,10 @@ class PipelineDriver:
         if not self._delta_track:
             raise RuntimeError("delta capture not enabled (enable_delta_capture)")
         self.flush()  # pending scatters + held emission belong to this epoch
-        arrays, meta = self._capture_delta(delivery_delta)
-        epoch = chain.append(arrays, meta)
+        with self._att_save.span():
+            arrays, meta = self._capture_delta(delivery_delta)
+            epoch = chain.append(arrays, meta)
+        self._att_save.count("bytes", chain.last_delta_bytes)
         self._delta_reset_capture()
         return epoch
 

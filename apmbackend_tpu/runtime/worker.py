@@ -1462,12 +1462,9 @@ class WorkerApp:
             # sampled traces whose lines this feed absorbs go live on the
             # driver first: their tick may fire inside this very feed
             self._drain_trace_fifo(self._ring_fed + n)
-        t0 = time.perf_counter() if self._att_feed.enabled else 0.0
         try:
-            with self._driver_lock:
+            with self._att_feed.span(), self._driver_lock:
                 fn()
-            if self._att_feed.enabled:
-                self._att_feed.add_busy(time.perf_counter() - t0)
         except Exception:
             # the device loop must survive a bad batch: a dead loop would
             # wedge the broker thread against a full ring forever. The batch

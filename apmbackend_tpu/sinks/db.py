@@ -294,9 +294,8 @@ class DBWriter:
         from ..obs.trace import get_tracer
 
         self._obs_tracer = get_tracer()
-        # wall-clock attribution (obs.attrib): insert flushes double as the
-        # sink_absorb stage's busy time — same perf_counter pair DBStats
-        # already pays
+        # wall-clock attribution (obs.attrib): each insert flush is a span
+        # of the sink_absorb stage — the interval DBStats already times
         self._att_absorb = get_attrib().clock(STAGE_SINK_ABSORB)
         if start_timer:
             self._thread = threading.Thread(target=self._timer_loop, daemon=True, name="dbwriter-timer")
@@ -364,7 +363,8 @@ class DBWriter:
             self._deadlines[etype] = None
         start = time.perf_counter()
         try:
-            self.executor.insert_many(self.column_sets[etype], drained)
+            with self._att_absorb.span():
+                self.executor.insert_many(self.column_sets[etype], drained)
         except Exception as e:
             if self.logger:
                 self.logger.error(f"Error during insert attempt: {e}")
@@ -381,7 +381,6 @@ class DBWriter:
         if self.db_stats is not None:
             self.db_stats.add_inserted(len(drained))
             self.db_stats.add_elapsed_ms(elapsed * 1000.0)
-        self._att_absorb.add_busy(elapsed)
         return True
 
     def process_all(self) -> None:

@@ -121,10 +121,12 @@ class Profiling:
         self.trace_allocations = bool(config.get("traceAllocations", False))
         self._prev_excepthook = None
         self._server_started = False
+        self._started_tracing = False
 
     def install(self, *, install_signal: bool = True) -> None:
         if self.trace_allocations and not tracemalloc.is_tracing():
             tracemalloc.start()
+            self._started_tracing = True
         if install_signal and hasattr(signal, "SIGUSR2"):
             try:
                 signal.signal(signal.SIGUSR2, lambda *_: self.dump())
@@ -172,3 +174,6 @@ class Profiling:
         if self._prev_excepthook is not None:
             sys.excepthook = self._prev_excepthook
             self._prev_excepthook = None
+        if self._started_tracing:  # left on, it slows every allocation in the process
+            tracemalloc.stop()
+            self._started_tracing = False

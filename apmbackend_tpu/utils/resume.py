@@ -43,17 +43,22 @@ def save_resume_file(path: str, obj: Any, *, logger=None, quiet: bool = True) ->
     if not quiet and logger:
         logger.info(f"Saving data to resume file: {path}")
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    from ..obs.attrib import STAGE_STATE_SAVE, get_attrib
+
+    clock = get_attrib().clock(STAGE_STATE_SAVE, counters=("bytes",), shared=True)
     # Atomic write: the reference's writeFileSync can leave a torn file on
     # crash, which its loader then discards; we avoid the data loss instead.
-    fd, tmp = tempfile.mkstemp(dir=os.path.dirname(os.path.abspath(path)), suffix=".tmp")
-    try:
-        with os.fdopen(fd, "w", encoding="utf-8") as fh:
-            json.dump(_sanitize(obj), fh, allow_nan=False)
-        os.replace(tmp, path)
-    except BaseException:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
-        raise
+    with clock.span():
+        fd, tmp = tempfile.mkstemp(dir=os.path.dirname(os.path.abspath(path)), suffix=".tmp")
+        try:
+            with os.fdopen(fd, "w", encoding="utf-8") as fh:
+                json.dump(_sanitize(obj), fh, allow_nan=False)
+                clock.count("bytes", fh.tell())
+            os.replace(tmp, path)
+        except BaseException:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
+            raise
     if not quiet and logger:
         logger.info(f"Resume file has been saved: {path}")
 

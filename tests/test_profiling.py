@@ -38,6 +38,16 @@ def test_snapshot_includes_tracemalloc_sites(tmp_path):
         p.uninstall()
 
 
+def test_uninstall_stops_the_allocation_tracing_it_started(tmp_path):
+    import tracemalloc
+
+    p = Profiling("m", {"heapSnapshotDir": str(tmp_path), "traceAllocations": True})
+    p.install(install_signal=False)
+    assert tracemalloc.is_tracing()
+    p.uninstall()
+    assert not tracemalloc.is_tracing()
+
+
 def test_memoryerror_hook_dumps_and_chains(tmp_path):
     seen = []
     prev = sys.excepthook
