@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import os
 import re
+import threading
 import time
 from dataclasses import dataclass, field
 from datetime import datetime
@@ -413,8 +414,20 @@ class TransactionParser:
         _att = get_attrib()
         self._att_scan = _att.clock(STAGE_PARSER_SCAN)
         self._att_pack = _att.clock(STAGE_FRAME_PACK)
-        self._att_sweep = _att.clock(STAGE_PARSER_SWEEP, counters=("released", "failed"))
+        self._att_sweep = _att.clock(
+            STAGE_PARSER_SWEEP, counters=("released", "failed", "examined")
+        )
         self._neednum_released = 0  # numberless records handed on at expiry
+        # One lock over the parser's state: the caches, the per-file
+        # contexts, the frame buffer and the native engine. Held by
+        # read_line, read_lines, sweep, drain and cache_stats, so the
+        # cache-sweep timer and the tail pump threads never interleave with
+        # a chunk; ctypes releases the GIL inside the native engine, which
+        # the GIL alone would leave shared. Reentrant: read_lines without
+        # the native engine feeds read_line. What the held sections call
+        # out to (on_record -> the producer's write_line, frame_sink, the
+        # logger) never calls back into the parser.
+        self._lock = threading.RLock()
         # logId -> acctNum (backfill source)
         self.acct_cache = TTLCache(acct_ttl_s, clock=clock)
         # the native ingest fast path (marker pre-filter + field extraction
@@ -441,7 +454,9 @@ class TransactionParser:
             )
         else:
             self.record_cache = TTLCache(record_ttl_s, clock=clock, on_expired=self._on_partial_expired)
-        # logId -> {service: joined-but-numberless record}; expiry = emit anyway
+        # logId -> {service: joined-but-numberless record}; expiry = emit
+        # anyway. A key is set only on a miss (_park_need_num), never again
+        # while live, so expiry hands records on in first-park order.
         self.need_num_cache = TTLCache(need_num_ttl_s, clock=clock, on_expired=self._on_neednum_expired)
 
     # -- cache expiry --------------------------------------------------------
@@ -466,32 +481,45 @@ class TransactionParser:
             )
             self._neednum_released += 1
 
+    def _examined(self) -> int:
+        """Deadlines the Python caches' sweeps have compared so far (the
+        native record cache is not counted)."""
+        return sum(
+            getattr(c, "examined", 0)
+            for c in (self.acct_cache, self.record_cache, self.need_num_cache)
+        )
+
     def sweep(self) -> None:
         """The cache-sweep timer's body: expire the three caches (expired
         numberless records are handed on) and flush buffered frames. One
-        ``parser_sweep`` span; counts the records released and the sweeps
-        that raised."""
+        ``parser_sweep`` span, taken once the parser lock is held; counts
+        the records released, the deadlines compared and the sweeps that
+        raised."""
         clk = self._att_sweep
-        released0 = self._neednum_released
-        try:
-            with clk.span():
-                self.acct_cache.sweep()
-                self.record_cache.sweep()
-                self.need_num_cache.sweep()
-                if self._frame_buf:
-                    self._flush_frames_safe("<sweep>")
-        except Exception:
-            clk.count("failed")
-            raise
-        finally:
-            clk.count("released", self._neednum_released - released0)
+        with self._lock:
+            released0 = self._neednum_released
+            examined0 = self._examined()
+            try:
+                with clk.span():
+                    self.acct_cache.sweep()
+                    self.record_cache.sweep()
+                    self.need_num_cache.sweep()
+                    if self._frame_buf:
+                        self._flush_frames_safe("<sweep>")
+            except Exception:
+                clk.count("failed")
+                raise
+            finally:
+                clk.count("released", self._neednum_released - released0)
+                clk.count("examined", self._examined() - examined0)
 
     def drain(self) -> None:
         """End-of-replay: flush numberless records out, drop partials."""
-        self.need_num_cache.flush_all()
-        self.record_cache.clear()
-        if self._frame_buf:
-            self._flush_frames_safe("<drain>")
+        with self._lock:
+            self.need_num_cache.flush_all()
+            self.record_cache.clear()
+            if self._frame_buf:
+                self._flush_frames_safe("<drain>")
 
     # -- frame emission ------------------------------------------------------
     def flush_frames(self) -> None:
@@ -546,11 +574,12 @@ class TransactionParser:
                 )
 
     def cache_stats(self) -> dict:
-        return {
-            "acct": self.acct_cache.stats(),
-            "record": self.record_cache.stats(),
-            "need": self.need_num_cache.stats(),
-        }
+        with self._lock:
+            return {
+                "acct": self.acct_cache.stats(),
+                "record": self.record_cache.stats(),
+                "need": self.need_num_cache.stats(),
+            }
 
     # -- record emission -----------------------------------------------------
     def _output(self, server, service, log_id, acct_num, start_ts, end_ts, elapsed, insert_to_db=False):
@@ -910,25 +939,26 @@ class TransactionParser:
 
         fatal (JS's out-of-range indexing yields undefined where Python would
         raise — fail-open is the equivalent robustness)."""
-        c = self.counters
-        c["lines_in"] += 1
-        t0 = time.perf_counter_ns()
-        try:
-            self._read_line(file_path, line)
-        except ConsumerError as e:
-            # downstream (engine/sink) failure, not bad input — surface loudly
-            if self.logger:
-                self.logger.error(
-                    f"Record consumer failed (record dropped) in {file_path}: "
-                    f"{e.__cause__!r}: {line[:200]!r}"
-                )
-        except Exception as e:
-            if self.logger:
-                self.logger.error(f"Unparseable log line in {file_path}: {e}: {line[:200]!r}")
-        finally:
-            dt = time.perf_counter_ns() - t0
-            c["parse_ns"] += dt
-            self._att_scan.add_busy(dt * 1e-9)
+        with self._lock:
+            c = self.counters
+            c["lines_in"] += 1
+            t0 = time.perf_counter_ns()
+            try:
+                self._read_line(file_path, line)
+            except ConsumerError as e:
+                # downstream (engine/sink) failure, not bad input — surface loudly
+                if self.logger:
+                    self.logger.error(
+                        f"Record consumer failed (record dropped) in {file_path}: "
+                        f"{e.__cause__!r}: {line[:200]!r}"
+                    )
+            except Exception as e:
+                if self.logger:
+                    self.logger.error(f"Unparseable log line in {file_path}: {e}: {line[:200]!r}")
+            finally:
+                dt = time.perf_counter_ns() - t0
+                c["parse_ns"] += dt
+                self._att_scan.add_busy(dt * 1e-9)
 
     # -- batch API (native ingest fast path) ---------------------------------
     def read_lines(self, file_path: str, data: Union[bytes, str]) -> int:
@@ -948,6 +978,10 @@ class TransactionParser:
             data = data.encode("utf-8", "replace")
         if not data:
             return 0
+        with self._lock:
+            return self._read_lines_locked(file_path, data)
+
+    def _read_lines_locked(self, file_path: str, data: bytes) -> int:
         self._obs_tracer.note_ingest_start()  # chunk-granular ingest anchor
         if self._native is None:
             segs = data.decode("utf-8", "replace").split("\n")

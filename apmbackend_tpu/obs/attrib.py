@@ -83,7 +83,7 @@ STAGE_TICK_REBUILD = "tick_rebuild"
 STAGE_TICK_TX_DRAIN = "tick_tx_drain"
 STAGE_TICK_EMIT = "tick_emit"
 STAGE_SINK_ABSORB = "sink_absorb"
-STAGE_PARSER_SWEEP = "parser_sweep"  # the cache-sweep timer (counters: released, failed)
+STAGE_PARSER_SWEEP = "parser_sweep"  # the cache-sweep timer (counters: released, failed, examined)
 STAGE_STATE_SAVE = "state_save"  # resume/checkpoint writes (counter: bytes)
 STAGE_INGEST_DISPATCH = "ingest_dispatch"  # host side of one ingest call (records, slots)
 STAGE_GC = "gc"  # the garbage collector's collections

@@ -3,6 +3,8 @@
 import math
 import os
 
+import pytest
+
 from apmbackend_tpu.ingest.parser import TransactionParser, convert_log_date_to_ms
 from apmbackend_tpu.ingest.replay import FixtureGenerator, ReplayDriver, write_fixture_logs
 from apmbackend_tpu.ingest.ttlcache import TTLCache
@@ -319,3 +321,114 @@ def test_app_log_ejb_marker_falls_through_to_app_state():
     # handler regression would have cached a partial under logId jb1
     assert parser.record_cache.get("jb1") is None
     assert parser.cache_stats()["record"]["keys"] == 0
+
+
+def _lock_stream(server, n_tx=1500, seed=7):
+    """(file, chunk) steps of a mixed stream from one server whose every
+    record comes out the same whenever the need-number sweep runs:
+    accounts arrive before the exits they join, and a parked record is
+    released carrying the account it was parked with."""
+    import random
+
+    gen = FixtureGenerator(server=server, seed=seed)
+    gen._next_id = seed * 10**6  # log ids unique across servers
+    rng = random.Random(seed)
+    steps = []
+    for i in range(n_tx):
+        kind = rng.random()
+        svc = f"svc{rng.randrange(8)}"
+        if kind < 0.3:
+            pairs = gen.soap_transaction(svc, rng.randint(50, 1200), acct=10**8 + i)
+        elif kind < 0.6:  # no account: parked for the 30 s TTL
+            pairs = gen.soap_transaction(svc, rng.randint(50, 1200))
+        elif kind < 0.85:  # BAF metadata account: parked with it
+            pairs = gen.standard_ct_transaction(svc, rng.randint(50, 1200), acct=10**8 + i,
+                                                baf_meta=True)
+        else:
+            pairs = gen.audit_trail([(f"Provider[{svc}]", rng.randint(5, 50)),
+                                     ("bcottag", rng.randint(5, 50))], acct=10**8 + i)
+        gen.advance(0.05)
+        for fname, line in pairs:
+            fname = f"{server}/{fname}"
+            if steps and steps[-1][0] == fname:
+                steps[-1][1].append(line)
+            else:
+                steps.append((fname, [line]))
+    return [(f, ("\n".join(lines) + "\n").encode()) for f, lines in steps]
+
+
+@pytest.mark.parametrize("feeders", [1, 4])
+@pytest.mark.parametrize("no_native", ["", "1"], ids=["native", "python"])
+def test_sweep_thread_beside_the_feed_changes_no_record(monkeypatch, no_native, feeders):
+    """The cache-sweep timer runs on its own thread beside the feed, and
+    the live tail path feeds from one pump thread per file; the parser
+    lock keeps them out of each other's chunks (ctypes releases the GIL
+    inside the native engine, so the GIL alone would not)."""
+    import sys
+    import threading
+
+    from apmbackend_tpu.obs.attrib import AttributionPlane, set_attrib
+
+    monkeypatch.setenv("APM_PARSE_NO_NATIVE", no_native)
+    streams = [_lock_stream(f"jvm{k}", n_tx=1500 // feeders, seed=7 + k) for k in range(feeders)]
+    # past the 30 s TTL over the whole feed, inside the 120 s ones
+    per_step = 90.0 / sum(len(steps) for steps in streams)
+
+    def run(concurrent):
+        now = [1000.0]
+        records = []
+        parser = TransactionParser(lambda tx, db: records.append((tx.to_csv(), db)),
+                                   server_from_path=lambda fp: fp.split("/")[0],
+                                   clock=lambda: now[0])
+        tick = threading.Lock()
+        stop, errors = threading.Event(), []
+
+        def feed(steps):
+            try:
+                for fname, chunk in steps:
+                    parser.read_lines(fname, chunk)
+                    with tick:
+                        now[0] += per_step
+            except Exception as e:  # pragma: no cover - the failure reported below
+                errors.append(e)
+
+        def sweeper():
+            try:
+                while not stop.is_set():
+                    parser.sweep()
+            except Exception as e:  # pragma: no cover - the failure reported below
+                errors.append(e)
+
+        if not concurrent:
+            for steps in streams:
+                feed(steps)
+        else:
+            sweep_thread = threading.Thread(target=sweeper, daemon=True)
+            feed_threads = [threading.Thread(target=feed, args=(steps,), daemon=True)
+                            for steps in streams]
+            sweep_thread.start()
+            for t in feed_threads:
+                t.start()
+            for t in feed_threads:
+                t.join(timeout=60)
+            stop.set()
+            sweep_thread.join(timeout=30)
+            assert not any(t.is_alive() for t in feed_threads + [sweep_thread])
+        parser.drain()
+        return parser, records, errors
+
+    old_plane = set_attrib(AttributionPlane(module="w"))
+    old_interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        parser, records, errors = run(concurrent=True)
+        st = parser._att_sweep.snapshot()
+        _, expected, _ = run(concurrent=False)
+    finally:
+        sys.setswitchinterval(old_interval)
+        set_attrib(old_plane)
+    assert (parser._native is None) == (no_native == "1")
+    assert errors == []
+    assert st["failed"] == 0 and st["events"] > 0 and st["released"] > 0
+    assert sorted(records) == sorted(expected)
+    assert len(expected) > 1500

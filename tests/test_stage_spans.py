@@ -171,6 +171,31 @@ def test_parser_sweep_counts_released_records_and_failed_sweeps(fresh_plane):
     assert (st["released"], st["failed"], st["events"]) == (1, 1, 3)
 
 
+@pytest.mark.parametrize("native", [True, False], ids=["native", "python"])
+def test_parser_sweep_examines_due_entries_not_live_ones(fresh_plane, native):
+    from apmbackend_tpu.ingest.parser import TransactionParser
+    from apmbackend_tpu.ingest.replay import FixtureGenerator
+
+    now = [0.0]
+    records = []
+    parser = TransactionParser(lambda tx, db: records.append(tx), use_native=native,
+                               server_from_path=lambda fp: "jvm1", clock=lambda: now[0])
+    for i in range(100_000):  # live for the 120 s account TTL
+        parser.acct_cache.set(f"acct{i}", "123456789")
+    gen = FixtureGenerator(server="jvm1")
+    for i in range(5):  # no account: parked for the 30 s need-number TTL
+        for fname, line in gen.soap_transaction(f"svc{i}", 100 + i):
+            parser.read_line(fname, line)
+    assert len(parser.need_num_cache) == 5 and records == []
+    now[0] = 31
+    parser.sweep()
+    assert sorted(tx.service for tx in records) == [f"S:svc{i}" for i in range(5)]
+    st = fresh_plane.stage_table()[STAGE_PARSER_SWEEP]
+    assert (st["released"], st["failed"]) == (5, 0)
+    assert st["examined"] <= st["released"] + 3
+    assert len(parser.acct_cache) == 100_000
+
+
 def _driver(micro_batch_size=512):
     from apmbackend_tpu.pipeline import PipelineDriver
 
