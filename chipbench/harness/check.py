@@ -24,11 +24,30 @@ and the float64 reference fed those records:
   p95 and the reference's, both at the wire's rounding (one decimal); on
   an exact rounding tie either neighbour counts (chip_smoke.py
   ``_wire_equal``).
-- ``zscore_mismatch``: z-score bands and signals of both lags against
-  ``GoldenZScore`` stepped at the same ticks.
+- ``zscore_mismatch``: z-score bands (avg, lb, ub) and signals of both
+  lags against ``ZScoreLists`` (``GoldenZScore`` stepped for every key at
+  once, in float64) fed the reference's rows as the wire rounds them
+  (``js_to_fixed``) at the same ticks. A band is off by more than the
+  wire's half digit plus ``BAND_RTOL`` of |avg| + thr x std, or a signal
+  differs, outside the slack of the wire's rounding ties in the list (a
+  float32 engine may round a tie the other way; the stats' own rule).
 - ``alerts_unexpected``: alert rows of sampled keys whose reference raised
   no signal.
 - ``rows_compared``: at least one row with transactions was compared.
+- ``bands_compared``: rows in which both sides hold avg, lb and ub for some
+  metric; at least 1 where the configuration has a ``history``, else
+  printed against 0.
+
+With a ``history`` (``harness/history.py``) the program restores a saved
+day at boot: every key of it is registered from the first tick, and each
+lag's lists start as the last ``lag`` values of the same series, so bands
+and signals are compared from the first tick. Without one, the upstream's
+lags (360, 8640) never fill in a run and both sides read no bands.
+
+Not covered: the alert rules' own verdicts. An alert needs
+``requiredNumberBadIntervalsInAlertWindowToTrigger`` (45) bad intervals in
+its window, more than a run's backlog spans, so only alerts that should
+not be there are counted.
 """
 
 from __future__ import annotations
@@ -42,7 +61,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .reference import GoldenZScore, WindowSpec, window_row
+from .reference import WindowSpec, ZScoreLists, js_to_fixed, window_row, wire_tie
 
 
 def wire_gap(got: Optional[float], want: float, digits: int) -> Optional[float]:
@@ -142,6 +161,12 @@ def read_sunk(db_path: str, pending_lines: Sequence[str]) -> Tuple[Counter, Coun
 
 StatRow = Tuple[tuple, int, int, Optional[float], dict]
 
+AT_LEAST = ("rows_compared", "bands_compared")  # readings held to a floor, not a ceiling
+
+# z-score avg / lb / ub: the wire's half digit plus this share of |avg| + thr x std
+BAND_RTOL = 1e-4
+WIRE_STEP = 0.1  # what a wire rounding tie may move a value by (one decimal)
+
 
 # A parked record leaves the parser when its entry in the need-number cache
 # expires: 30 s after it was parked (the upstream's TTL, stream_parse_
@@ -159,7 +184,7 @@ def _cut_counts(parked_k: List[tuple], window: range, before: int) -> List[int]:
 
 def reference_rows(expected: "Expected", keys: Sequence[tuple], spec: WindowSpec,
                    device_counts: Dict[tuple, int], fed_times: Sequence[float],
-                   row_fn=window_row):
+                   row_fn=window_row, registered: Sequence[tuple] = ()):
     """GoldenStats fed the engine-bound records in the order the parser
     hands them on, for the sampled ``keys``.
 
@@ -173,7 +198,9 @@ def reference_rows(expected: "Expected", keys: Sequence[tuple], spec: WindowSpec
     each takes in every record parked ``PARK_DUE_S`` or more before the
     tick's record was fed (``fed_times`` maps a feed sequence number to its
     monotonic time). ``cut_gap`` is the widest count gap at the best cut
-    within those bounds, 0 when one cut fits every key.
+    within those bounds, 0 when one cut fits every key. Keys in
+    ``registered`` (a restored registry) have a row at every tick, records
+    or not.
 
     Returns ({(key, edge): row}, the edges in tick order, cut_gap, the
     number of rows whose key the reference has not seen by that tick)."""
@@ -192,6 +219,8 @@ def reference_rows(expected: "Expected", keys: Sequence[tuple], spec: WindowSpec
         if key in want:
             parked[key].append((int(expected.rank[i]), labels[i], int(expected.elapsed[i])))
     seen: Dict[tuple, Dict[int, List[int]]] = defaultdict(lambda: defaultdict(list))
+    for key in want.intersection(registered):
+        seen[key] = defaultdict(list)
     rows: Dict[tuple, dict] = {}
     edges: List[int] = []
     cut_gap = 0
@@ -266,10 +295,12 @@ def reference_rows(expected: "Expected", keys: Sequence[tuple], spec: WindowSpec
 
 def compare_rows(rows: Sequence[StatRow], ref: Dict[tuple, dict], edges: Sequence[int],
                  keys: Sequence[tuple], lags: Sequence[Tuple[int, float, float]],
-                 tpm_div: float) -> dict:
+                 tpm_div: float, start: Optional[Dict[int, np.ndarray]] = None) -> dict:
     """Readings of the committed FullStat rows of ``keys`` against the
     reference's rows: missing_rows (either side), count_gap, null_mismatch,
-    stat_gap, zscore_mismatch, rows_compared, and the reference's signals."""
+    stat_gap, zscore_mismatch, bands_compared, rows_compared, and the
+    reference's signals. Each lag's z-score lists start as ``start[lag]``
+    ([len(keys), 3, n], oldest first) where given, else empty."""
     got: Dict[tuple, StatRow] = {}
     extra = 0
     for row in rows:
@@ -279,17 +310,23 @@ def compare_rows(rows: Sequence[StatRow], ref: Dict[tuple, dict], edges: Sequenc
         got[k] = row
     lag_ids = [lag for lag, _t, _i in lags]
     res = {"missing_rows": extra, "count_gap": 0, "null_mismatch": 0, "stat_gap": 0.0,
-           "zscore_mismatch": 0, "rows_compared": 0, "signals": Counter()}
+           "zscore_mismatch": 0, "bands_compared": 0, "rows_compared": 0,
+           "signals": Counter()}
     ref_keys = {(key, edge, lag) for (key, edge) in ref for lag in lag_ids}
     res["missing_rows"] += sum(1 for k in got if k not in ref_keys)
-    for key in keys:
-        for (lag, thr, infl) in lags:
-            z = GoldenZScore(lag, thr, infl)
-            for edge in edges:
-                want = ref.get((key, edge))
-                if want is None:
-                    continue  # the key had no record yet at this tick
-                zw = z.step(key, want["average"], want["per75"], want["per95"])
+    metrics = ("average", "per75", "per95")
+    for (lag, thr, infl) in lags:
+        z = ZScoreLists(len(keys), lag, thr, infl, None if start is None else start[lag])
+        for edge in edges:
+            idx = [i for i, key in enumerate(keys) if (key, edge) in ref]
+            if not idx:
+                continue  # no key had a record yet at this tick
+            wants = [ref[(keys[i], edge)] for i in idx]
+            news = [[js_to_fixed(w[m], 1) for m in metrics] for w in wants]
+            ties = [[WIRE_STEP if wire_tie(w[m], 1) else 0.0 for m in metrics] for w in wants]
+            zavg, zlb, zub, zsig, zslack = z.step(idx, news, ties)
+            for j, (i, want) in enumerate(zip(idx, wants)):
+                key = keys[i]
                 row = got.get((key, edge, lag))
                 if row is None:
                     res["missing_rows"] += 1
@@ -300,22 +337,37 @@ def compare_rows(rows: Sequence[StatRow], ref: Dict[tuple, dict], edges: Sequenc
                 else:
                     res["count_gap"] = max(res["count_gap"],
                                            abs(int(round(tpm * tpm_div)) - want["count"]))
-                for name in ("average", "per75", "per95"):
+                for name in metrics:
                     gap = wire_gap(st.get(name), want[name], 1)
                     if gap is None:
                         res["null_mismatch"] += 1
                     else:
                         res["stat_gap"] = max(res["stat_gap"], gap)
-                for name in ("average", "per75", "per95"):
-                    zr = zw[name]
-                    if zr["signal"]:
+                banded = False
+                for m, name in enumerate(metrics):
+                    signal = int(zsig[j, m])
+                    if signal:
                         res["signals"][key] += 1
-                    if (st.get(name + "signal") or 0) != zr["signal"]:
-                        res["zscore_mismatch"] += 1
-                    for part in ("avg", "lb", "ub"):
-                        gap = wire_gap(st.get(name + part), zr[part], 1)
-                        if gap is None or gap > 0.05 + 1e-4 * abs(zr[part]):
+                    # a bound's float32 error grows with its terms, avg and thr x std;
+                    # a tie pushed into the list may have gone the other way
+                    spread = float(zub[j, m] - zavg[j, m])
+                    scale = abs(float(zavg[j, m])) + (0.0 if math.isnan(spread) else spread)
+                    room = BAND_RTOL * scale + float(zslack[j, m])
+                    if (st.get(name + "signal") or 0) != signal:
+                        # only a value within that room of thr x std may fire on one side
+                        margin = abs(abs(news[j][m] - float(zavg[j, m])) - spread)
+                        if not margin <= room + ties[j][m]:
                             res["zscore_mismatch"] += 1
+                    both = True
+                    for part, arr in (("avg", zavg), ("lb", zlb), ("ub", zub)):
+                        want_part = float(arr[j, m])
+                        gap = wire_gap(st.get(name + part), want_part, 1)
+                        if gap is None or gap > 0.05 + room:
+                            res["zscore_mismatch"] += 1
+                        both = (both and st.get(name + part) is not None
+                                and not math.isnan(want_part))
+                    banded = banded or both
+                res["bands_compared"] += banded
                 if want["count"]:
                     res["rows_compared"] += 1
     return res
@@ -323,12 +375,18 @@ def compare_rows(rows: Sequence[StatRow], ref: Dict[tuple, dict], edges: Sequenc
 
 def judge(expected: Expected, db_path: str, pending_lines: Sequence[str], keys: Sequence[tuple],
           spec: WindowSpec, lags, *, fed_times: Sequence[float], dropped: int,
-          limits: Dict[str, float], control=None) -> dict:
+          limits: Dict[str, float], control=None, history=None) -> dict:
     """Every reading with its limit: {name: {"value", "limit", "ok"}}, plus
-    ``_failed`` (records lost or doubled anywhere). With ``control`` (a
-    row function such as ``reference.control_row``) the committed rows'
-    statistics are replaced by the control's at the same key and tick,
-    each rounded as the wire rounds it: the control in the program's place."""
+    ``_failed`` (records lost or doubled anywhere) and ``_reference`` (the
+    reference's signals and the sink's alert rows, of the sampled keys).
+    With ``control`` (a row function such as ``reference.control_row``) the
+    committed rows' statistics are replaced by the control's at the same
+    key and tick, each rounded as the wire rounds it: the control in the
+    program's place. With ``history`` (``harness.history.History``) every
+    key of it is registered from the start and the reference's z-score
+    lists start from its series."""
+    registered = () if history is None else history.keys
+    start = None if history is None else {lag: history.lists(keys, lag) for lag, _t, _i in lags}
     want = expected.multiset()
     sunk, pending, alerts = read_sunk(db_path, pending_lines)
     got = sunk + pending
@@ -337,30 +395,39 @@ def judge(expected: Expected, db_path: str, pending_lines: Sequence[str], keys: 
     rows = read_stat_rows(db_path, keys)
     counts = {(k, ts): int(round(tpm * spec.tpm_div)) for k, ts, _lag, tpm, _st in rows
               if tpm is not None}
-    ref, edges, cut_gap, unseen = reference_rows(expected, keys, spec, counts, fed_times)
+    ref, edges, cut_gap, unseen = reference_rows(expected, keys, spec, counts, fed_times,
+                                                 registered=registered)
     if control is not None:
-        cref, _e, _g, _u = reference_rows(expected, keys, spec, counts, fed_times, row_fn=control)
+        cref, _e, _g, _u = reference_rows(expected, keys, spec, counts, fed_times, row_fn=control,
+                                          registered=registered)
         def wire(v, digits):
             return None if v is None or math.isnan(v) else round(v, digits)
 
         rows = [(k, ts, lag, wire(cref[(k, ts)]["tpm"], 2),
                  dict(st, **{n: wire(cref[(k, ts)][n], 1) for n in ("average", "per75", "per95")}))
                 for k, ts, lag, _tpm, st in rows if (k, ts) in cref]
-    res = compare_rows(rows, ref, edges, keys, lags, spec.tpm_div)
+    res = compare_rows(rows, ref, edges, keys, lags, spec.tpm_div, start=start)
     res["count_gap"] = max(res["count_gap"], cut_gap)
     res["missing_rows"] += unseen
-    unexpected = sum(n for key, n in alerts.items() if key in set(keys) and not res["signals"][key])
+    sampled = set(keys)
+    unexpected = sum(n for key, n in alerts.items() if key in sampled and not res["signals"][key])
     readings = {
         "lost_records": lost, "extra_records": extra,
         "missing_rows": res["missing_rows"], "count_gap": res["count_gap"],
         "null_mismatch": res["null_mismatch"], "stat_gap": res["stat_gap"],
         "zscore_mismatch": res["zscore_mismatch"], "alerts_unexpected": unexpected,
-        "rows_compared": res["rows_compared"],
+        "rows_compared": res["rows_compared"], "bands_compared": res["bands_compared"],
     }
     out = {}
     for name, value in readings.items():
         limit = limits[name]
-        ok = value >= limit if name == "rows_compared" else value <= limit
+        ok = value >= limit if name in AT_LEAST else value <= limit
         out[name] = {"value": value, "limit": limit, "ok": bool(ok)}
     out["_failed"] = lost + extra
+    out["_reference"] = {
+        "signals": sum(res["signals"].values()),
+        "signal_keys": len([k for k, n in res["signals"].items() if n]),
+        "alert_rows": sum(n for key, n in alerts.items() if key in sampled),
+        "alert_keys": len([k for k in alerts if k in sampled]),
+    }
     return out

@@ -9,6 +9,10 @@ window lives in ``modes/<mode>.py``, named by the traffic file's
 (what was fed: lines per file, the feed sequence number of every line,
 and the monotonic time of each sequence number). A new mode is a new file.
 
+Where the configuration file has a ``history`` section, ``build_pipeline``
+first writes the saved day (``harness/history.py``) as the engine's resume
+file, which the worker restores at boot through its own path.
+
 Every mode warms up on the cell's own data, so every program the window
 runs has been compiled and run before it opens. Afterwards the fed prefix
 is drained through and the pipeline shut down, so the check sees
@@ -27,6 +31,8 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from .fleet import generate_fleet
+from .history import History, history_params, write_resume
+from .history import generate as generate_history
 from .reference import WindowSpec
 
 SPANS = ("feed", "settle")  # the harness's host spans in a trace
@@ -266,6 +272,11 @@ class Run:
         self.cell = cell
         self.cfg_file = cell.config
         self.traffic = cell.traffic
+        self.cfg = pipeline_config(self.cfg_file, work)
+        self.spec = window_spec(self.cfg)
+        self.lags = zscore_lags(self.cfg)
+        self.history_params = history_params(self.cfg_file, self.cfg)
+        self.history: Optional[History] = None
         self.seed = seed
         self.seconds = float(seconds)
         self.work = work
@@ -292,14 +303,24 @@ class Run:
     def build_pipeline(self, tail: bool = False) -> None:
         from apmbackend_tpu.standalone import StandalonePipeline
 
-        cfg = pipeline_config(self.cfg_file, self.work)
-        self.cfg = cfg
-        self.spec = window_spec(cfg)
-        self.lags = zscore_lags(cfg)
+        cfg = self.cfg
         self.db_path = cfg["streamInsertDb"]["dbFileFullPath"]
+        if self.history_params is not None:
+            self.write_history()
         self.pipe = StandalonePipeline(config=cfg, tail=tail, install_signals=False)
         for rt in (self.pipe.lead, self.pipe.parser_rt, self.pipe.sink_rt, self.pipe.jmx_rt):
             rt.qm.on("overflow", lambda _queue, n: self.evicted.append(int(n)))
+
+    def write_history(self) -> None:
+        """The configuration's saved day (``harness/history.py``), drawn from
+        the seed for the generated fleet's keys and written as the engine's
+        resume file, which the worker restores at boot."""
+        t0 = time.perf_counter()
+        self.history = generate_history(self.fleet, self.fleet.engine_keys(),
+                                        self.history_params, self.spec, self.lags,
+                                        self.traffic.get("records"), self.seed)
+        write_resume(self.cfg["tpuEngine"]["resumeFileFullPath"], self.history, self.cfg)
+        self.notes["history_s"] = time.perf_counter() - t0
 
     def saves_between(self, t0: float, t1: float) -> int:
         """Resume files the program's timers wrote between two

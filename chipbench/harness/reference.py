@@ -1,9 +1,15 @@
 """The plain float64 reference of the engine's statistics, and its control.
 
-Copied from ``tests/golden.py`` (``GoldenStats``, ``GoldenZScore``) and
+Copied from ``tests/golden.py`` (``GoldenStats``, ``GoldenZScore``),
 ``apmbackend_tpu/utils/jsmath.py`` (the reference's util_methods.js helpers,
-quirks included) so that it imports nothing of the program. It is fed the
-generator's records, never what the device ingested.
+quirks included) and ``apmbackend_tpu/entries.py`` (``js_to_fixed``) so that
+it imports nothing of the program. It is fed the generator's records, never
+what the device ingested.
+
+``ZScoreLists`` is ``GoldenZScore`` stepped for every key of one lag at
+once in numpy, the check's z-score reference; ``GoldenZScore`` stays as its
+test oracle. Both take each row's statistics as the wire carries them to
+the upstream's z-score process (``js_to_fixed``, one decimal).
 
 ``window_row`` is ``GoldenStats.generate_all`` for one key at one tick,
 over every record of the buckets the tick's window covers: the row the
@@ -21,6 +27,7 @@ lower-precision engine would give.
 from __future__ import annotations
 
 import math
+from decimal import ROUND_HALF_DOWN, ROUND_HALF_UP, Decimal
 from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
@@ -78,6 +85,31 @@ def js_percentile(sorted_values: Sequence[float], percentile: float) -> Optional
     if index == n - 1:
         return sorted_values[index]
     return (sorted_values[index] + sorted_values[index + 1]) / 2.0
+
+
+def js_to_fixed(x: float, digits: int = 1) -> float:
+    """``parseFloat(x.toFixed(digits))``: the nearest decimal to the exact
+    binary value, an exact tie toward +inf (Number.prototype.toFixed), as the
+    stats reach the z-score over the wire (StatEntry.toCSVString, entries.js:72).
+    NaN stays NaN."""
+    if math.isnan(x):
+        return NAN
+    q = Decimal(1).scaleb(-digits)
+    return float(Decimal(x).quantize(q, rounding=ROUND_HALF_UP if x >= 0 else ROUND_HALF_DOWN))
+
+
+# how near a rounding tie a float32 engine's value may fall on either side:
+# a few float32 epsilons of the value
+TIE_REACH = 4 * 2.0 ** -23
+
+
+def wire_tie(x: float, digits: int = 1) -> bool:
+    """Whether ``x`` lies at a rounding tie of ``js_to_fixed``, or within a
+    float32 engine's reach of one: there the engine may round either way."""
+    if math.isnan(x):
+        return False
+    t = abs(x) * 10.0 ** digits
+    return abs(t - math.floor(t) - 0.5) <= TIE_REACH * t
 
 
 # ------------------------------------------------------ GoldenStats copy ----
@@ -198,3 +230,120 @@ class GoldenZScore:
             lst.append(infl)
             out[metric] = {"avg": avg, "lb": lb, "ub": ub, "signal": sig}
         return out
+
+
+class ZScoreLists:
+    """``GoldenZScore`` of one lag stepped for many keys at once, in float64.
+
+    Row ``k`` holds key ``k``'s three lists (average, per75, per95) as a
+    ring: ``values[k, m, :]``, ``length[k]`` values pushed so far (at most
+    ``lag``) and ``pos[k]`` the slot the next push takes, which is the
+    oldest value once the list is full. Every quirk of ``_process_metric``
+    is kept: no statistics until ``length >= lag`` (NaN pushes count), NaN
+    entries skipped, a zero variance or all-equal valid entries give no
+    standard deviation (and the mean is then that value), a NaN new value
+    raises no signal, influence mixes in the list's last value unless it is
+    NaN, and the (damped) value is pushed, the oldest dropped, after the
+    statistics. Mean and variance are taken over the whole list at every
+    step, as the upstream takes them; only the order of the float64 sums
+    differs from ``GoldenZScore``'s.
+
+    ``start[k, m, :]`` (optional) are key ``k``'s lists before the first
+    step, oldest first, at most ``lag`` long.
+
+    Each entry may carry an uncertainty ``unc``: how far an engine's value
+    may lie from it (a wire rounding tie, ``wire_tie``). ``step`` returns a
+    ``slack`` that bounds what those do to avg and to thr x std: at most
+    sum(unc) / n and thr x sqrt(sum(unc^2) / n) over the n valid entries (the
+    standard deviation is a norm of the centred list)."""
+
+    def __init__(self, n_keys: int, lag: int, threshold: float, influence: float,
+                 start: Optional[np.ndarray] = None):
+        self.lag = int(lag)
+        self.threshold = float(threshold)
+        self.influence = float(influence)
+        self.values = np.full((n_keys, 3, self.lag), np.nan)
+        self.length = np.zeros(n_keys, np.int64)
+        if start is not None:
+            start = np.asarray(start, np.float64)
+            m = start.shape[-1]
+            if start.shape != (n_keys, 3, m) or m > self.lag:
+                raise ValueError(f"start lists of shape {start.shape} for {n_keys} keys, "
+                                 f"lag {self.lag}")
+            self.values[:, :, :m] = start
+            self.length[:] = m
+        self.pos = self.length % self.lag
+        self.unc = np.zeros((n_keys, 3, self.lag))  # pages untouched until a tie
+        self._unc_n = np.zeros((n_keys, 3), np.int64)  # entries with some uncertainty
+        self._unc_sum = np.zeros((n_keys, 3))
+        self._unc_sq = np.zeros((n_keys, 3))
+        self._dev = None
+
+    def _stats(self, rows: np.ndarray):
+        """(avg, std, valid entries) of ``rows``' lists, [len(rows), 3]; NaN
+        and 0 where the list is not full."""
+        n = len(rows)
+        avg = np.full((n, 3), np.nan)
+        std = np.full((n, 3), np.nan)
+        valid_n = np.zeros((n, 3))
+        full = np.nonzero(self.length[rows] >= self.lag)[0]
+        if len(full) == 0:
+            return avg, std, valid_n
+        sel = rows[full]
+        vals = self.values if len(sel) == len(self.values) and np.array_equal(
+            sel, np.arange(len(self.values))) else self.values[sel]
+        if self._dev is None or self._dev.shape != vals.shape:
+            self._dev = np.empty_like(vals)  # reused: a fresh buffer per step costs its page faults
+        valid = ~np.isnan(vals)
+        where = None if valid.all() else valid
+        cnt = np.full(vals.shape[:-1], self.lag) if where is None else valid.sum(-1)
+        with np.errstate(invalid="ignore", divide="ignore"):
+            mean = np.add.reduce(vals, axis=-1, where=True if where is None else where) / cnt
+            dev = np.subtract(vals, mean[..., None], out=self._dev)
+            np.square(dev, out=dev)
+            var = np.add.reduce(dev, axis=-1, where=True if where is None else where) / cnt
+        lo = np.fmin.reduce(vals, axis=-1)  # fmin and fmax pass over NaN
+        hi = np.fmax.reduce(vals, axis=-1)
+        same = (cnt > 0) & (lo == hi)
+        sd = np.where(var > 0, np.sqrt(np.where(var > 0, var, 0.0)), np.nan)
+        avg[full] = np.where(same, lo, mean)
+        std[full] = np.where(same, np.nan, sd)
+        valid_n[full] = cnt
+        return avg, std, valid_n
+
+    def step(self, rows: Sequence[int], new: np.ndarray, unc: Optional[np.ndarray] = None):
+        """One step of the keys ``rows`` with their new values ``new`` and
+        those values' uncertainties ``unc`` (0 where left out), each
+        [len(rows), 3]: (avg, lb, ub, signal, slack), each [len(rows), 3]."""
+        rows = np.asarray(rows, np.int64)
+        new = np.asarray(new, np.float64).reshape(len(rows), 3)
+        unc = np.zeros_like(new) if unc is None else np.asarray(unc, np.float64).reshape(new.shape)
+        unc = np.where(np.isnan(new), 0.0, unc)
+        avg, std, valid_n = self._stats(rows)
+        n = np.maximum(valid_n, 1)
+        slack = np.where(valid_n > 0, self._unc_sum[rows] / n
+                         + self.threshold * np.sqrt(self._unc_sq[rows] / n), 0.0)
+        lb = avg - self.threshold * std
+        ub = avg + self.threshold * std
+        with np.errstate(invalid="ignore"):
+            dev = new - avg
+            fired = np.abs(dev) > self.threshold * std  # False wherever a NaN is in it
+        signal = np.where(fired, np.where(dev > 0, 1, -1), 0)
+        pos = self.pos[rows]
+        last = self.values[rows, :, (pos - 1) % self.lag]
+        has_last = (self.length[rows] > 0)[:, None] & ~np.isnan(last)
+        damped = fired & has_last
+        pushed = np.where(damped, self.influence * new + (1 - self.influence) * last, new)
+        last_unc = self.unc[rows, :, (pos - 1) % self.lag]
+        pushed_unc = np.where(damped, self.influence * unc + (1 - self.influence) * last_unc, unc)
+        gone = self.unc[rows, :, pos] * (self.length[rows] >= self.lag)[:, None]
+        left = self._unc_n[rows] + (pushed_unc > 0) - (gone > 0)
+        self._unc_n[rows] = left
+        self._unc_sum[rows] = np.where(left > 0, self._unc_sum[rows] + pushed_unc - gone, 0.0)
+        self._unc_sq[rows] = np.where(left > 0, self._unc_sq[rows] + pushed_unc ** 2 - gone ** 2,
+                                      0.0)
+        self.values[rows, :, pos] = pushed
+        self.unc[rows, :, pos] = pushed_unc
+        self.pos[rows] = (pos + 1) % self.lag
+        self.length[rows] = np.minimum(self.length[rows] + 1, self.lag)
+        return avg, lb, ub, signal, slack

@@ -31,11 +31,13 @@ WORK = os.path.join(ROOT, ".benchwork")  # gitignored: logs, sink, traces of one
 
 # the limit of each number compared; stat_gap (ms) lies between the program's
 # widest reading over its seeds (0.0) and the bfloat16 control's narrowest
-# (11.6), nearer the control (PERF.md, "How correct is decided")
+# (11.6), nearer the control (PERF.md, "How correct is decided");
+# bands_compared is held to 1 where the configuration has a history
+# (``limits``), since only then can a z-score list be full
 LIMITS = {
     "lost_records": 0, "extra_records": 0, "missing_rows": 0, "count_gap": 0,
     "null_mismatch": 0, "stat_gap": 6.0, "zscore_mismatch": 0, "alerts_unexpected": 0,
-    "rows_compared": 1,
+    "rows_compared": 1, "bands_compared": 0,
 }
 SAMPLED_KEYS = 512
 
@@ -72,6 +74,11 @@ def layer_metrics(cell, run) -> dict:
     return out, reading
 
 
+def limits(run) -> dict:
+    """``LIMITS`` for this run's configuration."""
+    return dict(LIMITS, bands_compared=1 if run.history_params is not None else 0)
+
+
 def measure(args, t_start: float, control=None) -> dict:
     """One run of the cell on the chip; its result line as a dict. With
     ``control`` (a row function such as ``reference.control_row``) the result
@@ -100,7 +107,7 @@ def run_cell(cell, args, t_start: float, devices, control=None, work: str = WORK
     os.makedirs(work)
     trace_dir = os.path.join(work, "trace") if args.trace else None
 
-    from chipbench.harness.check import judge, sample_keys
+    from chipbench.harness.check import AT_LEAST, judge, sample_keys
     from chipbench.harness.drive import Run
 
     run = Run(cell, args.seed, args.seconds, trace_dir, work, workers=workers,
@@ -127,11 +134,15 @@ def run_cell(cell, args, t_start: float, devices, control=None, work: str = WORK
 
     def check(row_fn=None) -> dict:
         return judge(expected, run.db_path, pending, keys, run.spec, run.lags,
-                     fed_times=run.fed_times, dropped=run.dropped, limits=LIMITS,
-                     control=row_fn)
+                     fed_times=run.fed_times, dropped=run.dropped, limits=limits(run),
+                     control=row_fn, history=run.history)
 
+    t_check = time.perf_counter()
     checks = check()
+    run.notes["check_s"] = time.perf_counter() - t_check
     failed = checks.pop("_failed")
+    run.notes["reference"] = checks.pop("_reference")
+    say(f"check took {run.notes['check_s']:.3f} s; reference {json.dumps(run.notes['reference'])}")
     run.checks = checks
     correct = all(c["ok"] for c in checks.values())
 
@@ -154,12 +165,13 @@ def run_cell(cell, args, t_start: float, devices, control=None, work: str = WORK
     if control is not None:
         cchecks = check(control)
         cchecks.pop("_failed")
+        cchecks.pop("_reference")
         run.control_checks = cchecks
         result["control_correct"] = all(c["ok"] for c in cchecks.values())
         result["control"] = {name: c["value"] for name, c in cchecks.items()}
     result["checks"] = {name: [c["value"], c["limit"]] for name, c in checks.items()}
     for name, c in checks.items():
-        rel = ">=" if name == "rows_compared" else "<="
+        rel = ">=" if name in AT_LEAST else "<="
         say(f"check {name} {c['value']} (limit {rel} {c['limit']}) {'ok' if c['ok'] else 'FAIL'}")
     return result, run
 
