@@ -398,13 +398,14 @@ class RecoveredChain:
     dict plus the chain position the writer continues from."""
 
     def __init__(self, data: Optional[dict], epoch: int, chain_id: str,
-                 tail_uid: str, base_epoch: int, dropped: List[str]):
+                 tail_uid: str, base_epoch: int, dropped: List[str], bytes_read: int = 0):
         self.data = data
         self.epoch = epoch  # last committed epoch the chain recovers to
         self.chain_id = chain_id
         self.tail_uid = tail_uid
         self.base_epoch = base_epoch
         self.dropped = dropped  # invalid/foreign tail files (diagnostics)
+        self.bytes_read = bytes_read  # the base's and the replayed segments' bytes
 
 
 class DeltaChain:
@@ -611,6 +612,7 @@ class DeltaChain:
                 data = {name: npz[name] for name in npz.files}
             cm = json.loads(data.pop("chain_meta").item())
             chain_id, uid = cm["chain"], cm.get("uid", "")
+            nbytes = os.path.getsize(path)
         except Exception as e:
             if self.logger:
                 self.logger.error(f"Checkpoint base unreadable (falling back): {path}: {e}")
@@ -647,7 +649,8 @@ class DeltaChain:
                 break
             try:
                 with open(seg, "rb") as fh:
-                    header, arrays = _decode_segment(fh.read())
+                    blob = fh.read()
+                header, arrays = _decode_segment(blob)
                 if header.get("chain") != chain_id:
                     raise InvalidSegment("foreign chain id (stale tail)")
                 if header.get("prev_uid") != uid:
@@ -660,7 +663,8 @@ class DeltaChain:
                 break
             epoch += 1
             uid = header["uid"]
-        return RecoveredChain(data, epoch, chain_id, uid, base_epoch, dropped)
+            nbytes += len(blob)
+        return RecoveredChain(data, epoch, chain_id, uid, base_epoch, dropped, nbytes)
 
     # -- the per-epoch hot path ----------------------------------------------
     def append(self, arrays: Dict[str, np.ndarray], meta: dict) -> int:

@@ -81,10 +81,13 @@ STAGE_WORKER_FEED = "worker_feed"
 STAGE_TICK_DISPATCH = "tick_dispatch"
 STAGE_TICK_REBUILD = "tick_rebuild"
 STAGE_TICK_TX_DRAIN = "tick_tx_drain"
-STAGE_TICK_EMIT = "tick_emit"
+STAGE_TICK_EMIT = "tick_emit"  # counters: banded, signals (emitted rows)
 STAGE_SINK_ABSORB = "sink_absorb"
 STAGE_PARSER_SWEEP = "parser_sweep"  # the cache-sweep timer (counters: released, failed, examined)
 STAGE_STATE_SAVE = "state_save"  # resume/checkpoint writes (counter: bytes)
+# resume/checkpoint reads at boot (counters: bytes, keys, valid_slots,
+# load_ms, install_ms, derive_ms)
+STAGE_STATE_RESTORE = "state_restore"
 STAGE_INGEST_DISPATCH = "ingest_dispatch"  # host side of one ingest call (records, slots)
 STAGE_GC = "gc"  # the garbage collector's collections
 
@@ -101,6 +104,7 @@ SPAN_STAGES = (
     STAGE_TICK_EMIT,
     STAGE_SINK_ABSORB,
     STAGE_STATE_SAVE,
+    STAGE_STATE_RESTORE,
     STAGE_GC,
 )
 

@@ -31,8 +31,8 @@ The per-step cost depends on the variance mode:
   The ring becomes write-mostly cold storage whose only remaining jobs are
   exact periodic rebuilds (every ``rebuild_every`` ticks, one fused pass,
   cancelling float drift) and snapshot/restore (the aggregate is DERIVED
-  state: ``build_agg`` reconstructs it from the ring, so resume files keep
-  their schema). The zero-variance quirk stays EXACT via a run-length
+  state: ``restore_agg`` reconstructs it from the ring, so resume files
+  keep their schema). The zero-variance quirk stays EXACT via a run-length
   counter: the window's valid entries are precisely the last ``cnt`` valid
   pushes, so "all window values equal" ⟺ "the maximal equal suffix of
   valid pushes covers them" (``run_len >= cnt``) — no min/max scan needed.
@@ -84,8 +84,7 @@ class ZScoreConfig(NamedTuple):
     # robust lags (both need/keep the full-window computation).
     sliding: bool = False
     # exact full-ring rebuild cadence for the sliding aggregates (ticks);
-    # bounds float drift AND the post-restore blind spot of the run-length
-    # all-equal guard. Amortized cost = 1/rebuild_every of one ring pass.
+    # bounds float drift. Amortized cost = 1/rebuild_every of one ring pass.
     rebuild_every: int = 64
 
     @property
@@ -100,8 +99,8 @@ class ZScoreConfig(NamedTuple):
 class SlidingAgg(NamedTuple):
     """Incremental window aggregates for ``ZScoreConfig.sliding`` mode.
 
-    Everything here is derived from the values ring (``build_agg``), so it is
-    never serialized; restore rebuilds it. Invariants between rebuilds:
+    Everything here is derived from the values ring (``restore_agg``), so it
+    is never serialized; restore rebuilds it. Invariants between rebuilds:
     ``cnt`` is the count of valid (non-NaN) window entries; ``vsum``/
     ``vsumsq`` are the sums of (x - anchor) and (x - anchor)^2 over them,
     with the per-row ``anchor`` frozen since the last rebuild (or the row's
@@ -109,9 +108,9 @@ class SlidingAgg(NamedTuple):
     data-spread scale — mean = anchor + vsum/cnt, var = vsumsq/cnt -
     (vsum/cnt)^2 — so neither the raw-sum ulp loss (magnitude ~1e6 windows)
     nor the E[x^2] - mean^2 cancellation can poison f32 variance.
-    ``run_len`` is a lower bound on the equal-suffix length of valid pushes
-    that is tight whenever it matters (run_len >= cnt ⟺ window all-equal);
-    ``last_valid`` is the most recent non-NaN pushed value (storage-rounded).
+    ``run_len`` is the equal-suffix length of valid pushes (capped at L + 1),
+    so run_len >= cnt ⟺ window all-equal; ``last_valid`` is the most recent
+    non-NaN pushed value (storage-rounded).
     """
 
     cnt: jnp.ndarray  # [S, 3] int32
@@ -170,7 +169,8 @@ def init_state(cfg: ZScoreConfig) -> ZScoreState:
 
 
 def build_agg(values: jnp.ndarray, cfg: ZScoreConfig, pos=None, anchor=None) -> SlidingAgg:
-    """Exact SlidingAgg from a values ring (restore path / periodic rebuild).
+    """Exact SlidingAgg from a values ring (periodic rebuild; the restore
+    path's ``restore_agg`` builds on it).
 
     Without ``anchor``: two fused passes — the first finds the window mean
     to anchor around, the second takes the anchored sums (the restore path,
@@ -179,12 +179,10 @@ def build_agg(values: jnp.ndarray, cfg: ZScoreConfig, pos=None, anchor=None) -> 
     window's value range keeps the moment cancellation benign, so an
     estimate is as good as the exact mean and the rebuild halves its ring
     traffic. ``pos`` (the global cursor; 0 when omitted) locates slot g-1
-    for the ``last_push`` mirror. ``run_len``/``last_valid`` are only
-    recoverable for all-equal windows (min == max); other rows restart at 0,
-    which is conservative — the guard can only under-detect until the row's
-    pushes re-establish the run or the window truly becomes all-equal
-    through >= cnt equal pushes (both exact going forward; module
-    docstring)."""
+    for the ``last_push`` mirror. ``run_len``/``last_valid`` come out only
+    for all-equal windows (min == max) and 0/NaN elsewhere: a rebuild keeps
+    the step's own counters for those rows (``rebuild_agg_state``), and a
+    restore scans for them (``restore_agg``)."""
     L = values.shape[-1]
     vals = values.astype(cfg.dtype) if values.dtype != cfg.dtype else values
     valid = ~jnp.isnan(vals)
@@ -205,6 +203,31 @@ def build_agg(values: jnp.ndarray, cfg: ZScoreConfig, pos=None, anchor=None) -> 
         last_valid=jnp.where(all_eq, vmax, jnp.nan).astype(cfg.dtype),
         last_push=last_push.astype(cfg.dtype),
     )
+
+
+def restore_agg(values: jnp.ndarray, cfg: ZScoreConfig, pos) -> SlidingAgg:
+    """``build_agg`` with the run-length guard made exact: the restore-time
+    derivation (pipeline.engine_derive_aggs), run once per restore.
+
+    ``last_valid`` is the newest valid push and ``run_len`` the length of
+    the equal suffix of valid pushes, both read back in push order from
+    slot ``pos - 1``. ``build_agg`` can only prove all-equal rows and
+    leaves the others at 0, which misses a list whose older entries age
+    out until its equal suffix is all that is left (say, a key idle for a
+    lag after a restart): the engine would band it on float residue where
+    the upstream sees zero variance. The per-tick rebuilds keep
+    ``build_agg``: between rebuilds the step keeps the counters exact."""
+    L = values.shape[-1]
+    vals = values.astype(cfg.dtype) if values.dtype != cfg.dtype else values
+    valid = ~jnp.isnan(vals)
+    age = (jnp.asarray(pos, jnp.int32) - 1 - jnp.arange(L, dtype=jnp.int32)) % L  # 0 = newest
+    newest = jnp.min(jnp.where(valid, age, L), axis=-1, keepdims=True)
+    last_valid = jnp.max(jnp.where(valid & (age == newest), vals, -jnp.inf), axis=-1)
+    last_valid = jnp.where(newest[..., 0] < L, last_valid, jnp.nan)
+    other = valid & (vals != last_valid[..., None])
+    first_other = jnp.min(jnp.where(other, age, L), axis=-1, keepdims=True)
+    run_len = jnp.sum(valid & (age < first_other), axis=-1, dtype=jnp.int32)
+    return build_agg(values, cfg, pos)._replace(run_len=run_len, last_valid=last_valid)
 
 
 def normalize_legacy_ring(values_np, fill_np, pos_np, L: int):
@@ -230,10 +253,12 @@ def rebuild_agg_state(state: ZScoreState, cfg: ZScoreConfig) -> ZScoreState:
     """Amortized exact rebuild of the sliding aggregates — called from the
     HOST loop every ``cfg.rebuild_every`` ticks (pipeline.engine_rebuild_aggs;
     it cannot ride inside the jitted step, whose contract is to never touch
-    the whole ring). Cancels float drift in the running sums, refreshes the
-    variance anchor to the current mean, and repairs the run-length all-equal
-    guard for rows whose constancy predates the aggregates (post-restore
-    blind spot, module docstring). No-op for non-sliding configs."""
+    the whole ring). Cancels float drift in the running sums and refreshes
+    the variance anchor to the current mean. ``run_len``/``last_valid``
+    keep the step's counters except on rows ``build_agg`` proves all-equal,
+    which take its values; the counters are exact since the restore
+    (``restore_agg``), so that merge decides no guard differently.
+    No-op for non-sliding configs."""
     if not cfg.sliding_active or state.agg is None:
         return state
     old = state.agg
@@ -260,7 +285,7 @@ def rebuild_chunk_rows(capacity: int, rebuild_every: int) -> int:
     per tick, so the worst tick pays ~1/rebuild_every of the full pass
     instead of one tick absorbing it all (the monolithic rebuild_agg_state
     stalled a tick for seconds at pod shapes). Every row's rebuild interval
-    stays <= rebuild_every ticks — the drift/blind-spot bound is unchanged."""
+    stays <= rebuild_every ticks — the drift bound is unchanged."""
     return max(1, -(-capacity // max(rebuild_every, 1)))
 
 

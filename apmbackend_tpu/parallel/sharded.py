@@ -514,7 +514,7 @@ class ShardedRebuildScheduler(_StaggeredRebuildBase):
     row chunk on EVERY shard simultaneously (the chunk offset is
     shard-local, so all shards rotate in lockstep through their own row
     blocks); a full rotation spans ``cfg.zscore_rebuild_every`` ticks, same
-    drift/blind-spot bound as the monolithic make_sharded_rebuild pass with
+    drift bound as the monolithic make_sharded_rebuild pass with
     no tick ever stalling on a whole-ring reduction. Purely shard-local —
     the aggregates are per-row, so no collectives ride the rebuild.
 

@@ -435,7 +435,7 @@ def make_fused_step(cfg: EngineConfig, *, integrate_rebuild: bool = True):
     of a ring the same program DUS-writes forces a whole-ring copy on
     XLA:CPU (measured 736 ms at [8192, 3, 8640]). Every row is still
     exactly re-aggregated once per ``zscore_rebuild_every`` ticks — the
-    drift/blind-spot bound is phase-shifted by one tick, not weakened.
+    drift bound is phase-shifted by one tick, not weakened.
 
     Unlike the staged executor the rebuild chunk here is XLA, not the
     native streaming kernel — at the small shapes the fused path targets,
@@ -968,7 +968,7 @@ class RebuildScheduler(_StaggeredRebuildBase):
     contiguous row chunk (rebuild_chunk_rows sizes it so a full rotation
     spans ``cfg.zscore_rebuild_every`` ticks) and returns the new state.
     Every row's rebuild interval stays <= rebuild_every ticks — the same
-    drift/blind-spot bound as the monolithic pass, minus the multi-second
+    drift bound as the monolithic pass, minus the multi-second
     tick stall at pod shapes (the reference pays its window recompute on
     EVERY entry, stream_calc_z_score.js:66-104; this is the amortized
     replacement being staggered).
@@ -1056,15 +1056,18 @@ class RebuildScheduler(_StaggeredRebuildBase):
         return state._replace(zscores=tuple(zs))
 
 
+_restore_agg = jax.jit(dzscore.restore_agg, static_argnums=1)
+
+
 def engine_derive_aggs(state: EngineState, cfg: EngineConfig) -> EngineState:
     """Derive the sliding aggregates from freshly-restored rings — the ONE
-    restore-time derivation, shared by the npz load_resume and the orbax
-    checkpoint restore (the aggregates are never serialized; SlidingAgg
-    docstring)."""
+    restore-time derivation, shared by the npz load_resume, the delta-chain
+    replay and the orbax checkpoint restore (the aggregates are never
+    serialized; SlidingAgg docstring). One compiled program per lag."""
     zstates = []
     for z, spec in zip(state.zscores, cfg.lags):
         zc = zscore_cfg(cfg, spec)
-        agg = dzscore.build_agg(z.values, zc, z.pos) if zc.sliding_active else None
+        agg = _restore_agg(z.values, zc, z.pos) if zc.sliding_active else None
         zstates.append(z._replace(agg=agg))
     return state._replace(zscores=tuple(zstates))
 
@@ -1392,7 +1395,7 @@ class PipelineDriver:
                 "dispatch": _att.clock(STAGE_TICK_DISPATCH),
                 "rebuild": _att.clock(STAGE_TICK_REBUILD),
                 "tx_drain": _att.clock(STAGE_TICK_TX_DRAIN),
-                "emit": _att.clock(STAGE_TICK_EMIT),
+                "emit": _att.clock(STAGE_TICK_EMIT, counters=("banded", "signals")),
             }
         else:
             from .obs.attrib import NULL_CLOCK
@@ -1401,12 +1404,24 @@ class PipelineDriver:
             self._trace = None
             self._decisions = None
             self._att_tick = dict.fromkeys(("dispatch", "rebuild", "tx_drain", "emit"), NULL_CLOCK)
-        # the host side of each ingest dispatch and each state save, with
-        # what they moved (records and padded slots; bytes written)
-        from .obs.attrib import STAGE_INGEST_DISPATCH, STAGE_STATE_SAVE, get_attrib
+        # the host side of each ingest dispatch, each state save and each
+        # restore, with what they moved (records and padded slots; bytes
+        # written; bytes read, keys and valid ring slots restored, and the
+        # restore's three phases)
+        from .obs.attrib import (
+            STAGE_INGEST_DISPATCH,
+            STAGE_STATE_RESTORE,
+            STAGE_STATE_SAVE,
+            get_attrib,
+        )
 
         self._att_ingest = get_attrib().clock(STAGE_INGEST_DISPATCH, counters=("records", "slots"))
         self._att_save = get_attrib().clock(STAGE_STATE_SAVE, counters=("bytes",), shared=True)
+        self._att_restore = get_attrib().clock(
+            STAGE_STATE_RESTORE,
+            counters=("bytes", "keys", "valid_slots", "load_ms", "install_ms", "derive_ms"),
+            shared=True,
+        )
         self._refresh_params()
         # emission pipelining (tpuEngine.asyncEmission / the async_emission
         # kwarg; default OFF): hold each tick's TickEmission and fetch it
@@ -2155,6 +2170,7 @@ class PipelineDriver:
         need_fs = self.on_fullstat is not None
         need_csv = self.on_fullstat_csv is not None
         need_alert = self.on_alert is not None or self.alerts_manager is not None
+        emit_clk = self._att_tick["emit"]
         for chan_id, lag_em in channels:
             if not (need_fs or need_csv or need_alert):
                 continue
@@ -2162,6 +2178,9 @@ class PipelineDriver:
             lb = np.asarray(lag_em.lower_bound)[:count]
             ub = np.asarray(lag_em.upper_bound)[:count]
             sig = np.asarray(lag_em.signal)[:count]
+            if emit_clk.enabled:  # FullStat rows with a band / a signal on some metric
+                emit_clk.count("banded", int(np.count_nonzero((~np.isnan(lb)).any(axis=1))))
+                emit_clk.count("signals", int(np.count_nonzero(sig.any(axis=1))))
             trig = np.asarray(lag_em.trigger)[:count]
             bits = np.asarray(lag_em.cause_bits)[:count]
             w_l, lo_l, up_l, sg_l = wavg.tolist(), lb.tolist(), ub.tolist(), sig.tolist()
@@ -2623,12 +2642,16 @@ class PipelineDriver:
 
         if isinstance(chain, str):
             chain = DeltaChain(chain, logger=self.logger)
-        rec = chain.load()
-        if rec is None or rec.data is None:
-            return False
-        self.drain_emission()  # pre-restore emissions belong to the old stream
-        if not self._install_resume_data(rec.data, f"chain {chain.directory}"):
-            return False
+        with self._att_restore.span():
+            t0 = time.perf_counter()
+            rec = chain.load()
+            if rec is None or rec.data is None:
+                return False
+            self._att_restore.count("load_ms", 1e3 * (time.perf_counter() - t0))
+            self._att_restore.count("bytes", rec.bytes_read)
+            self.drain_emission()  # pre-restore emissions belong to the old stream
+            if not self._install_resume_data(rec.data, f"chain {chain.directory}"):
+                return False
         if self._delta_track:
             self._delta_reset_capture()
         return True
@@ -2637,25 +2660,44 @@ class PipelineDriver:
         if not os.path.exists(path):
             return False
         self.drain_emission()  # pre-restore emissions belong to the old stream
-        # Fully materialize the snapshot before touching any state: np.load
-        # succeeds on any readable zip, and member reads (KeyError, zlib
-        # errors on truncation) raise lazily — a corrupt file must mean
-        # "start fresh", never a crash or a half-mutated driver.
-        try:
-            with np.load(path, allow_pickle=True) as npz:
-                data = {name: npz[name] for name in npz.files}
-        except Exception:
-            if self.logger:
-                self.logger.error(f"Could not load resume snapshot (starting fresh): {path}")
-            return False
-        return self._install_resume_data(data, path)
+        with self._att_restore.span():
+            t0 = time.perf_counter()
+            # Fully materialize the snapshot before touching any state:
+            # np.load succeeds on any readable zip, and member reads
+            # (KeyError, zlib errors on truncation) raise lazily — a corrupt
+            # file must mean "start fresh", never a crash or a half-mutated
+            # driver.
+            try:
+                with np.load(path, allow_pickle=True) as npz:
+                    data = {name: npz[name] for name in npz.files}
+            except Exception:
+                if self.logger:
+                    self.logger.error(f"Could not load resume snapshot (starting fresh): {path}")
+                return False
+            self._att_restore.count("load_ms", 1e3 * (time.perf_counter() - t0))
+            self._att_restore.count("bytes", os.path.getsize(path))
+            return self._install_resume_data(data, path)
+
+    def _install_resume_data(self, data: dict, source: str) -> bool:
+        """Install a restored full-snapshot ``data`` dict (npz schema) —
+        shared by the npz path and the delta-chain replay, so a chain
+        restore is bit-identical to restoring a full snapshot of the same
+        state — on the ``state_restore`` clock (``_install_state``)."""
+        return self._install_state(data, source, clock=self._att_restore)
 
     # apm: sync-boundary: resume install materializes host arrays onto the device once at boot
-    def _install_resume_data(self, data: dict, source: str) -> bool:
+    def _install_state(self, data: dict, source: str, clock=None) -> bool:
         """Install a full-snapshot ``data`` dict (npz schema) into the live
-        driver — shared by the npz path and the delta-chain replay, so a
-        chain restore is bit-identical to restoring a full snapshot of the
-        same state. Validation failure means "start fresh", never a crash."""
+        driver: a restore's, or a partition handoff's re-install of the
+        rows it keeps. Validation failure means "start fresh", never a
+        crash. A restore passes its ``state_restore`` clock: the install
+        then counts keys and valid ring slots and waits for the device
+        twice, after the host arrays are on it and after the aggregates are
+        derived, so that ``install_ms`` and ``derive_ms`` hold each phase's
+        device time (``derive_ms`` with the derivation's compile, on a
+        process's first restore). A handoff passes none and neither counts
+        nor waits."""
+        t0 = time.perf_counter()
         try:
             keys = [tuple(k.split("\x00", 1)) for k in data["registry"].tolist()]
             required = ["latest_bucket", "counts", "sums", "samples", "nsamples"]
@@ -2704,6 +2746,8 @@ class PipelineDriver:
         ring_dtype = self.cfg.zscore_ring_dtype or self.cfg.stats.dtype
         for spec in self.cfg.lags:
             values_np = pad_rows(data[f"z{spec.lag}_values"])
+            if clock is not None:
+                clock.count("valid_slots", int(np.count_nonzero(~np.isnan(values_np))))
             fill_np = pad_rows(data[f"z{spec.lag}_fill"])
             pos_np = np.asarray(data[f"z{spec.lag}_pos"])
             if pos_np.ndim == 0:
@@ -2746,15 +2790,21 @@ class PipelineDriver:
                 )
             )
             ecounters.append(jnp.asarray(pad_rows(data[f"{ek}_counters"])))
+        installed = EngineState(
+            stats_state, tuple(zstates), tuple(counters), tuple(estates), tuple(ecounters)
+        )
+        if clock is not None:
+            jax.block_until_ready(installed)
+            t_derive = time.perf_counter()
+            clock.count("install_ms", 1e3 * (t_derive - t0))
         # the sliding aggregates are DERIVED state: rebuilt exactly from the
         # restored rings, so snapshot schemas are unchanged and pre-sliding
         # snapshots restore 1:1 (shared derivation: engine_derive_aggs)
-        self.state = engine_derive_aggs(
-            EngineState(
-                stats_state, tuple(zstates), tuple(counters), tuple(estates), tuple(ecounters)
-            ),
-            self.cfg,
-        )
+        self.state = engine_derive_aggs(installed, self.cfg)
+        if clock is not None:
+            jax.block_until_ready(self.state)
+            clock.count("derive_ms", 1e3 * (time.perf_counter() - t_derive))
+            clock.count("keys", len(keys))
         self._latest_label = int(data["latest_bucket"])
         self.delivery_state = None
         if "delivery_state" in data:  # optional: absent for at-most-once
@@ -2865,7 +2915,7 @@ class PipelineDriver:
              if not self._pending_line_matches(ln, pred)],
             dtype=object,
         )
-        if not self._install_resume_data(data, "partition-release"):
+        if not self._install_state(data, "partition-release"):
             raise RuntimeError("row removal re-install failed")
         if self._delta_track:
             self._delta_reset_capture()
@@ -2948,7 +2998,7 @@ class PipelineDriver:
         )
         if "delivery_state" in cur:
             merged["delivery_state"] = cur["delivery_state"]
-        if not self._install_resume_data(merged, "partition-adopt"):
+        if not self._install_state(merged, "partition-adopt"):
             raise RuntimeError("row import re-install failed")
         if self._delta_track:
             self._delta_reset_capture()

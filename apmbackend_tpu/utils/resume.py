@@ -68,9 +68,14 @@ def load_resume_file(path: str, *, logger=None) -> Optional[Any]:
         if logger:
             logger.warning(f"Resume file does not exist, will not resume data: {path}")
         return None
+    from ..obs.attrib import STAGE_STATE_RESTORE, get_attrib
+
+    clock = get_attrib().clock(STAGE_STATE_RESTORE, counters=("bytes",), shared=True)
     try:
-        with open(path, "r", encoding="utf-8") as fh:
-            return _revive(json.load(fh))
+        with clock.span(), open(path, "r", encoding="utf-8") as fh:
+            data = _revive(json.load(fh))
+            clock.count("bytes", os.fstat(fh.fileno()).st_size)
+            return data
     except (ValueError, OSError):
         # ValueError covers JSONDecodeError AND UnicodeDecodeError: a torn
         # write can truncate mid-multibyte-sequence, which fails the utf-8

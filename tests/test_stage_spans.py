@@ -2,7 +2,8 @@
 accumulates as ``add_busy`` does and lands on the profiler's host plane;
 counters ride the clock into ``snapshot()``, ``/attrib`` and the registry;
 the spans and counters the program keeps at its stage boundaries
-(``parser_sweep``, ``ingest_dispatch``, ``state_save``, ``gc``)."""
+(``parser_sweep``, ``ingest_dispatch``, ``state_save``, ``state_restore``,
+``tick_emit``, ``gc``)."""
 
 import gc
 import glob
@@ -25,7 +26,9 @@ from apmbackend_tpu.obs.attrib import (
     STAGE_GC,
     STAGE_INGEST_DISPATCH,
     STAGE_PARSER_SWEEP,
+    STAGE_STATE_RESTORE,
     STAGE_STATE_SAVE,
+    STAGE_TICK_EMIT,
     AttributionPlane,
     StageClock,
     get_attrib,
@@ -196,12 +199,14 @@ def test_parser_sweep_examines_due_entries_not_live_ones(fresh_plane, native):
     assert len(parser.acct_cache) == 100_000
 
 
-def _driver(micro_batch_size=512):
+def _driver(micro_batch_size=512, lags=None, **kw):
     from apmbackend_tpu.pipeline import PipelineDriver
 
     cfg = default_config()
     cfg["tpuEngine"]["serviceCapacity"] = 16
-    return PipelineDriver(cfg, micro_batch_size=micro_batch_size)
+    if lags is not None:
+        cfg["streamCalcZScore"]["defaults"] = lags
+    return PipelineDriver(cfg, micro_batch_size=micro_batch_size, **kw)
 
 
 def _txs(n, label=170_000_000):
@@ -244,6 +249,98 @@ def test_state_save_spans_engine_and_resume_file_writes(tmp_path, fresh_plane):
     st = fresh_plane.stage_table()[STAGE_STATE_SAVE]
     assert st["events"] == 2
     assert st["bytes"] == os.path.getsize(path) + os.path.getsize(alerts)
+
+
+def _tick_through(drv, intervals, spike_at=None, label0=170_000_000):
+    """Six transactions on each of three keys per 10 s interval, their
+    elapsed times moving from one interval to the next; x20 at ``spike_at``."""
+    for k in range(intervals):
+        label = label0 + k
+        for i in range(18):
+            elapsed = (100 + 7 * i + 37 * (k % 5)) * (20 if k == spike_at else 1)
+            drv.feed(TxEntry("jvm1", f"S:s{i % 3}", f"l{k}-{i}", "1",
+                             label * 10000 + i - elapsed, label * 10000 + i, elapsed, "Y"))
+        drv.flush()
+
+
+@pytest.mark.parametrize("source", ["npz", "chain"])
+def test_state_restore_spans_the_driver_restore_and_resume_file_reads(
+        tmp_path, fresh_plane, source):
+    from apmbackend_tpu.deltachain import DeltaChain
+    from apmbackend_tpu.utils.resume import load_resume_file, save_resume_file
+
+    drv = _driver(lags=[{"LAG": 4, "THRESHOLD": 3.0, "INFLUENCE": 0.0},
+                        {"LAG": 6, "THRESHOLD": 3.0, "INFLUENCE": 0.0}])
+    _tick_through(drv, 12)
+    if source == "npz":
+        path = str(tmp_path / "engine.resume.npz")
+        drv.save_resume(path)
+        with np.load(path, allow_pickle=True) as npz:
+            data = {name: npz[name] for name in npz.files}
+        read = os.path.getsize(path)
+    else:
+        chain = DeltaChain(str(tmp_path / "chain"), fsync=False)
+        data = drv._capture_resume_arrays(None)
+        chain.initialize(data, epoch=0)
+        read = sum(os.path.getsize(p) for p in glob.glob(str(tmp_path / "chain" / "base-*")))
+    assert fresh_plane.stage_table()[STAGE_STATE_RESTORE]["events"] == 0  # saving reads nothing
+
+    fresh = _driver(lags=[{"LAG": 4, "THRESHOLD": 3.0, "INFLUENCE": 0.0},
+                          {"LAG": 6, "THRESHOLD": 3.0, "INFLUENCE": 0.0}])
+    assert STAGE_STATE_RESTORE in SPAN_STAGES
+    if source == "npz":
+        assert fresh.load_resume(path)
+    else:
+        assert fresh.load_resume_chain(str(tmp_path / "chain"))
+    st = fresh_plane.stage_table()[STAGE_STATE_RESTORE]
+    valid = sum(int(np.count_nonzero(~np.isnan(data[f"z{lag}_values"]))) for lag in (4, 6))
+    assert valid > 0
+    assert st["events"] == 1 and st["busy_s"] > 0.0
+    assert (st["bytes"], st["keys"], st["valid_slots"]) == (read, 3, valid)
+    assert all(st[c] > 0.0 for c in ("load_ms", "install_ms", "derive_ms"))
+    assert st["load_ms"] + st["install_ms"] + st["derive_ms"] <= 1e3 * st["busy_s"]
+
+    alerts = str(tmp_path / "alerts.resume")
+    save_resume_file(alerts, {"alerts": {"a": 1}, "alertBuffer": []})
+    assert load_resume_file(alerts) == {"alerts": {"a": 1}, "alertBuffer": []}
+    assert load_resume_file(str(tmp_path / "absent.resume")) is None  # no restore, no span
+    st = fresh_plane.stage_table()[STAGE_STATE_RESTORE]
+    assert st["events"] == 2 and st["bytes"] == read + os.path.getsize(alerts)
+
+
+def test_partition_handoff_is_no_restore(fresh_plane, monkeypatch):
+    """A handoff re-installs rows through the resume path but reads no
+    state: it leaves ``state_restore`` as it was and waits on no device."""
+    import jax
+
+    drv = _driver(lags=[{"LAG": 4, "THRESHOLD": 3.0, "INFLUENCE": 0.0}])
+    _tick_through(drv, 6)
+    waits = []
+    monkeypatch.setattr(jax, "block_until_ready", lambda x: waits.append(x) or x)
+    data = drv.export_service_rows(lambda srv, svc: svc == "S:s1")
+    assert drv.remove_service_rows(lambda srv, svc: svc == "S:s1") == 1
+    assert drv.import_service_rows(data) == 1
+    assert waits == []
+    st = fresh_plane.stage_table().get(STAGE_STATE_RESTORE, {})
+    assert st.get("events", 0) == 0
+    assert all(st.get(c, 0) == 0 for c in ("keys", "valid_slots", "install_ms", "derive_ms"))
+
+
+def test_tick_emit_counts_banded_and_signalled_rows(fresh_plane):
+    rows = []
+    drv = _driver(lags=[{"LAG": 3, "THRESHOLD": 2.0, "INFLUENCE": 0.0}], on_fullstat=rows.append)
+    _tick_through(drv, 24, spike_at=14)
+    drv.drain_emission()
+    st = fresh_plane.stage_table()[STAGE_TICK_EMIT]
+    parts = ("average", "per75", "per95")
+
+    def held(v):
+        return v is not None and v == v
+
+    banded = sum(any(held(getattr(r, p + "_lb")) for p in parts) for r in rows)
+    signals = sum(any(getattr(r, p + "_signal") for p in parts) for r in rows)
+    assert banded > 0 and signals > 0
+    assert (st["banded"], st["signals"]) == (banded, signals)
 
 
 def test_shared_clock_loses_no_update_across_threads(fresh_plane):

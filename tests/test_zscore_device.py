@@ -792,6 +792,86 @@ def test_sliding_build_agg_restore_parity():
         )
 
 
+def _restore_case(case, rng, S, lag):
+    """Restored lists [S, 3, lag] (oldest first) and the pushes [T, S, 3]
+    that follow them, for one case of the restore derivation's test."""
+    wide = lambda *shape: rng.uniform(50.0, 5000.0, shape)  # noqa: E731
+    nan = np.full((1, S, 3), np.nan)
+    lists = wide(S, 3, lag)
+    if case == "newest_only":
+        # NaN pushes age everything out but the newest valid entry; then a
+        # value far from it, which may not signal against zero variance
+        pushes = np.concatenate([np.repeat(nan, lag - 1, 0), wide(2, S, 3)])
+    elif case == "equal_suffix":
+        k = lag // 3
+        lists[..., -k:] = lists[..., -1:]
+        pushes = np.concatenate([np.repeat(lists[None, ..., -1], lag - k, 0),
+                                 lists[None, ..., -1] + 500.0, wide(2, S, 3)])
+    elif case == "all_equal":
+        lists[...] = lists[..., :1]
+        pushes = np.concatenate([lists[None, ..., 0] * 3.0, wide(lag, S, 3)])
+    else:  # mixed: holes in the lists and the pushes, and a spike
+        lists[rng.rand(S, 3, lag) < 0.2] = np.nan
+        pushes = wide(2 * lag, S, 3)
+        pushes[rng.rand(2 * lag, S) < 0.2] = np.nan
+        pushes[lag, 0] *= 40.0
+    return lists.astype(np.float32), pushes.astype(np.float32)
+
+
+@pytest.mark.parametrize("case", ["newest_only", "equal_suffix", "all_equal", "mixed"])
+def test_restore_derivation_matches_golden(case):
+    """The engine restored from saved lists (``restore_agg``, what
+    ``engine_derive_aggs`` runs) then stepped on the sliding path gives the
+    float64 upstream's bands and signals: where the valid entries left in a
+    list are all equal there is no band, however the list got there. The
+    ring is written at a cursor off 0, as a restored engine's usually is."""
+    S, lag, thr, infl, pos = 6, 8, 3.0, 0.2, 5
+    rng = np.random.RandomState(sum(map(ord, case)))
+    lists, pushes = _restore_case(case, rng, S, lag)
+
+    golden = GoldenZScore(lag, thr, infl)
+    for row in range(S):
+        golden.lists[("s", f"svc{row}")] = {
+            m: [float(v) for v in lists[row, i]] for i, m in enumerate(METRICS)}
+    cfg = dz.ZScoreConfig(S, lag, jnp.float32, sliding=True, rebuild_every=10_000)
+    slots = (pos - lag + np.arange(lag)) % lag  # oldest ... newest at pos - 1
+    ring = np.empty_like(lists)
+    ring[..., slots] = lists
+    values = jnp.asarray(ring)
+    state = dz.ZScoreState(values=values, fill=jnp.full(S, lag, jnp.int32),
+                           pos=jnp.asarray(pos, jnp.int32),
+                           agg=jax.jit(dz.restore_agg, static_argnums=1)(values, cfg, pos))
+    step = jax.jit(dz.step, static_argnums=1)
+    thr_v, infl_v = jnp.full(S, thr, jnp.float32), jnp.full(S, infl, jnp.float32)
+    # the running float32 sums hold squares of the largest values the list
+    # has held: their rounding moves a band by up to thr x sqrt(lag x eps) of
+    # that value, whatever the band's own width (a drift the periodic
+    # rebuild cancels, and no rebuild runs here)
+    room = thr * math.sqrt(lag * np.finfo(np.float32).eps) * float(np.nanmax(np.abs(
+        np.concatenate([lists.ravel(), pushes.ravel()]))))
+    banded = 0
+    for t, vals in enumerate(pushes):
+        res, state = step(state, cfg, jnp.asarray(vals), thr_v, infl_v)
+        res = jax.device_get(res)
+        for row in range(S):
+            want = golden.step("s", f"svc{row}", *map(float, vals[row]))
+            for i, m in enumerate(METRICS):
+                g = want[m]
+                got = {"avg": res.window_avg[row, i], "lb": res.lower_bound[row, i],
+                       "ub": res.upper_bound[row, i]}
+                where = (case, t, row, m, g, got, int(res.signal[row, i]))
+                for f in ("avg", "lb", "ub"):
+                    assert math.isnan(g[f]) == math.isnan(got[f]), where
+                    if not math.isnan(g[f]):
+                        assert abs(float(got[f]) - g[f]) <= room, where
+                # a signal may differ only within that room of thr x std
+                if int(res.signal[row, i]) != g["signal"]:
+                    margin = abs(abs(float(vals[row, i]) - g["avg"]) - (g["ub"] - g["avg"]))
+                    assert margin <= room, where
+                banded += not math.isnan(g["lb"])
+    assert banded > 0
+
+
 def test_sliding_grow_state_continues():
     cfg = dz.ZScoreConfig(4, 5, jnp.float32, sliding=True)
     state = dz.init_state(cfg)
