@@ -82,7 +82,7 @@ STAGE_TICK_DISPATCH = "tick_dispatch"
 STAGE_TICK_REBUILD = "tick_rebuild"
 STAGE_TICK_TX_DRAIN = "tick_tx_drain"
 STAGE_TICK_EMIT = "tick_emit"  # counters: banded, signals (emitted rows)
-STAGE_SINK_ABSORB = "sink_absorb"
+STAGE_SINK_ABSORB = "sink_absorb"  # insert flushes (counters: rows, lines: rows from tx lines)
 STAGE_PARSER_SWEEP = "parser_sweep"  # the cache-sweep timer (counters: released, failed, examined)
 STAGE_STATE_SAVE = "state_save"  # resume/checkpoint writes (counter: bytes)
 # resume/checkpoint reads at boot (counters: bytes, keys, valid_slots,

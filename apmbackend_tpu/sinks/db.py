@@ -19,8 +19,11 @@ Role parity with the reference's terminal stage (stream_insert_db.js):
 
 The executor is pluggable: a fake (in-memory, for tests — the seam the
 reference never had), SQLite (stdlib, always available), or Postgres (gated on
-a driver being installed; the reference's production target). Executors own
-value adaptation (datetime -> ISO-8601, dict -> JSON for the jsonb columns).
+a driver being installed; the reference's production target). Executors take
+insert tuples in column order (``insert_rows``). A tx line is buffered as it
+arrived and turned into its tuple at flush, with no Entry, dict or datetime
+per row; other rows are dicts, adapted column by column (datetime -> ISO-8601,
+dict -> JSON for the jsonb columns).
 """
 
 from __future__ import annotations
@@ -30,9 +33,11 @@ import math
 import threading
 import time
 from datetime import datetime, timezone
+from itertools import groupby
+from operator import itemgetter
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-from ..entries import Entry, EntryFactory
+from ..entries import Entry, EntryFactory, js_parse_int
 from ..utils.counters import DBStats
 from ..utils.resume import load_resume_file, save_resume_file
 
@@ -104,7 +109,54 @@ def _adapt(value):
     return value
 
 
-class FakeExecutor:
+def adapt_rows(cs: ColumnSet, rows: List[dict]) -> List[tuple]:
+    """Dict rows -> insert tuples in ``cs`` column order."""
+    return [tuple(_adapt(r.get(c)) for c in cs.columns) for r in rows]
+
+
+# the tx wire fields (tx|server|service|logid|acctnum|startts|endts|elapsed|
+# toplevel) in the tx column order (endts, startts, server, service, logid,
+# acctnum, elapsed, toplevel)
+_TX_FIELDS = itemgetter(6, 5, 1, 2, 3, 4, 7, 8)
+
+# Below 2**33 s (year 2242) the double nearest ms / 1000.0 is within half a
+# microsecond of the exact value, so fromtimestamp rounds it back to the
+# exact millisecond: the ISO string is the whole second's plus ``ms % 1000``.
+_EXACT_MS = (1 << 33) * 1000
+
+# whole seconds held in a writer's ISO-prefix cache before it starts over
+_ISO_PREFIX_CACHE = 4096
+_MS_SUFFIX = tuple(f"{ms:03d}Z" for ms in range(1000))
+
+_LINE_ERRORS = (IndexError, ValueError, OverflowError, OSError)
+
+
+def _is_line(row) -> bool:
+    return type(row) is str
+
+
+def _tx_int(field: str) -> Optional[int]:
+    """acctnum/elapsed column value: ``int(js_parse_int(field))``, NaN -> None."""
+    if len(field) < 16 and field.isascii() and field.isdigit():
+        return int(field)  # under 2**53: the float round trip is exact
+    if field == "NaN":
+        return None
+    v = js_parse_int(field)
+    return None if v != v else int(v)
+
+
+class Executor:
+    """The executors' shared surface: ``insert_rows`` takes insert tuples in
+    column order; ``insert_many`` adapts dict rows to them first."""
+
+    def insert_rows(self, cs: ColumnSet, values: List[tuple]) -> None:
+        raise NotImplementedError
+
+    def insert_many(self, cs: ColumnSet, rows: List[dict]) -> None:
+        self.insert_rows(cs, adapt_rows(cs, rows))
+
+
+class FakeExecutor(Executor):
     """In-memory executor for tests: records every batch; can be told to fail."""
 
     def __init__(self):
@@ -113,13 +165,11 @@ class FakeExecutor:
         self.scripts: List[str] = []
         self.fail = False
 
-    def insert_many(self, cs: ColumnSet, rows: List[dict]) -> None:
+    def insert_rows(self, cs: ColumnSet, values: List[tuple]) -> None:
         if self.fail:
             raise RuntimeError("injected insert failure")
-        table = self.tables.setdefault(cs.table, [])
-        for row in rows:
-            table.append(tuple(_adapt(row.get(c)) for c in cs.columns))
-        self.batches.append((cs.table, len(rows)))
+        self.tables.setdefault(cs.table, []).extend(values)
+        self.batches.append((cs.table, len(values)))
 
     def execute_script(self, sql: str) -> None:
         self.scripts.append(sql)
@@ -128,7 +178,7 @@ class FakeExecutor:
         pass
 
 
-class SQLiteExecutor:
+class SQLiteExecutor(Executor):
     """SQLite executor (stdlib). Tables are created on demand with TEXT-affinity
     columns — SQLite's dynamic typing keeps numerics numeric."""
 
@@ -143,17 +193,14 @@ class SQLiteExecutor:
         self._created: set = set()
         self._lock = threading.Lock()
 
-    def insert_many(self, cs: ColumnSet, rows: List[dict]) -> None:
+    def insert_rows(self, cs: ColumnSet, values: List[tuple]) -> None:
         cols = ", ".join(cs.columns)
         ph = ", ".join("?" for _ in cs.columns)
         with self._lock:
             if cs.table not in self._created:
                 self._conn.execute(f"CREATE TABLE IF NOT EXISTS {cs.table} ({cols})")
                 self._created.add(cs.table)
-            self._conn.executemany(
-                f"INSERT INTO {cs.table} ({cols}) VALUES ({ph})",
-                [tuple(_adapt(r.get(c)) for c in cs.columns) for r in rows],
-            )
+            self._conn.executemany(f"INSERT INTO {cs.table} ({cols}) VALUES ({ph})", values)
             self._conn.commit()
 
     def execute_script(self, sql: str) -> None:
@@ -167,7 +214,7 @@ class SQLiteExecutor:
             self._conn.close()
 
 
-class PostgresExecutor:  # pragma: no cover - requires a driver + live server
+class PostgresExecutor(Executor):  # pragma: no cover - requires a driver + live server
     """Postgres executor (the production target, stream_insert_db.js:133-143).
 
     Gated: constructed only when a driver (psycopg2 or pg8000) is importable.
@@ -199,9 +246,8 @@ class PostgresExecutor:  # pragma: no cover - requires a driver + live server
         self._driver = driver
         self._lock = threading.Lock()
 
-    def insert_many(self, cs: ColumnSet, rows: List[dict]) -> None:
+    def insert_rows(self, cs: ColumnSet, values: List[tuple]) -> None:
         cols = ", ".join(cs.columns)
-        values = [tuple(_adapt(r.get(c)) for c in cs.columns) for r in rows]
         with self._lock:
             if self._driver == "psycopg2":
                 ph = ", ".join("%s" for _ in cs.columns)
@@ -281,7 +327,11 @@ class DBWriter:
         self.clock = clock
         self._factory = EntryFactory()
         self._lock = threading.RLock()
-        self._buffers: Dict[str, List[dict]] = {t: [] for t in self.column_sets}
+        # dict rows, and in the tx buffer also the tx wire lines themselves
+        # (converted to insert tuples at flush, _line_values)
+        self._buffers: Dict[str, List] = {t: [] for t in self.column_sets}
+        # whole UTC second -> its ISO-8601 prefix ("2023-11-14T22:13:20.")
+        self._iso_prefix: Dict[int, str] = {}
         # Deadline per type, armed on first insert into an empty buffer
         # (stream_insert_db.js:332-343); None = disarmed.
         self._deadlines: Dict[str, Optional[float]] = {t: None for t in self.column_sets}
@@ -295,32 +345,46 @@ class DBWriter:
 
         self._obs_tracer = get_tracer()
         # wall-clock attribution (obs.attrib): each insert flush is a span
-        # of the sink_absorb stage — the interval DBStats already times
-        self._att_absorb = get_attrib().clock(STAGE_SINK_ABSORB)
+        # of the sink_absorb stage — the interval DBStats already times —
+        # counting the rows inserted and those of them converted from tx
+        # lines; shared, since the timer thread flushes too
+        self._att_absorb = get_attrib().clock(
+            STAGE_SINK_ABSORB, counters=("rows", "lines"), shared=True)
         if start_timer:
             self._thread = threading.Thread(target=self._timer_loop, daemon=True, name="dbwriter-timer")
             self._thread.start()
 
     # -- intake --------------------------------------------------------------
     def consume_line(self, line: str, headers: Optional[dict] = None) -> None:
-        """CSV line off the db_insert queue (consumeMsg, stream_insert_db.js:355-376)."""
+        """CSV line off the db_insert queue (consumeMsg, stream_insert_db.js:355-376).
+
+        A tx line is buffered as it is and becomes its insert tuple at
+        flush (``_line_values``); every other line becomes its Entry here."""
+        if line.startswith("tx|"):
+            if headers:
+                self._trace_absorb(headers, "tx")
+            self.add("tx", line)
+            return
         entry = self._factory.from_csv(line)
         if entry is None:
             if self.logger:
                 self.logger.info(f"Entry undefined: {line}")
             return
         if headers:
-            tid = headers.get("trace_id")
-            if tid is not None:
-                # sampled message: mark the sink absorb under its trace_id
-                now = time.time()
-                start = headers.get("ingest_ts")
-                self._obs_tracer.span(
-                    tid, "sink",
-                    now if start is None else float(start), now,
-                    entry_type=entry.type,
-                )
+            self._trace_absorb(headers, entry.type)
         self.add_entry(entry)
+
+    def _trace_absorb(self, headers: dict, entry_type: str) -> None:
+        tid = headers.get("trace_id")
+        if tid is not None:
+            # sampled message: mark the sink absorb under its trace_id
+            now = time.time()
+            start = headers.get("ingest_ts")
+            self._obs_tracer.span(
+                tid, "sink",
+                now if start is None else float(start), now,
+                entry_type=entry_type,
+            )
 
     def add_entry(self, entry: Entry) -> None:
         if entry.type not in self.column_sets:
@@ -335,7 +399,7 @@ class DBWriter:
             return
         self.add(entry.type, obj)
 
-    def add(self, etype: str, obj: dict) -> None:
+    def add(self, etype: str, obj: dict | str) -> None:
         with self._lock:
             flush_now = len(self._buffers[etype]) >= self.buffer_limit
         # Reference order: flush the full buffer first, then append
@@ -361,10 +425,14 @@ class DBWriter:
                 return True
             self._buffers[etype] = []
             self._deadlines[etype] = None
+        cs = self.column_sets[etype]
+        kept = drained
         start = time.perf_counter()
         try:
             with self._att_absorb.span():
-                self.executor.insert_many(self.column_sets[etype], drained)
+                values, kept, n_lines = self._values(cs, drained)
+                if values:
+                    self.executor.insert_rows(cs, values)
         except Exception as e:
             if self.logger:
                 self.logger.error(f"Error during insert attempt: {e}")
@@ -372,16 +440,129 @@ class DBWriter:
                 # Drained rows go back in FRONT of anything newer
                 # (stream_insert_db.js:310-320) and the timeout re-arms so
                 # retry happens even if no new rows arrive.
-                self._buffers[etype] = drained + self._buffers[etype]
+                self._buffers[etype] = kept + self._buffers[etype]
                 if self._deadlines[etype] is None:
                     self._deadlines[etype] = self.clock() + self.max_ms / 1000.0
                     self._wake.set()
             return False
         elapsed = time.perf_counter() - start
+        if not values:
+            return True
+        self._att_absorb.count("rows", len(values))
+        self._att_absorb.count("lines", n_lines)
         if self.db_stats is not None:
-            self.db_stats.add_inserted(len(drained))
+            self.db_stats.add_inserted(len(values))
             self.db_stats.add_elapsed_ms(elapsed * 1000.0)
         return True
+
+    def _values(self, cs: ColumnSet, rows: list) -> Tuple[List[tuple], list, int]:
+        """Insert tuples of buffered rows, in arrival order: each run of tx
+        lines in one columnar pass, dict rows adapted column by column.
+        Returns the tuples, the rows that gave them (a line the old
+        Entry path rejected is logged and dropped) and how many came from
+        lines."""
+        values: List[tuple] = []
+        kept: list = []
+        n_lines = 0
+        for is_line, run in groupby(rows, key=_is_line):
+            run = list(run)
+            if is_line:
+                got, run = self._line_values(run)
+                n_lines += len(got)
+            else:
+                got = adapt_rows(cs, run)
+            values += got
+            kept += run
+        return values, kept, n_lines
+
+    def _line_values(self, lines: List[str]) -> Tuple[List[tuple], List[str]]:
+        """Insert tuples of tx lines, each equal to
+        ``tuple(_adapt(v) for v in from_csv(line).to_postgres().values())``,
+        with no Entry, dict or datetime per line. Returns them and the lines
+        that gave them: when a line has too few fields or a timestamp out of
+        datetime's range, each line is taken on its own, and a line that
+        fails takes the Entry path, which logs and drops it."""
+        try:
+            return self._line_columns(lines), lines
+        except _LINE_ERRORS:
+            pass
+        values, kept = [], []
+        for line in lines:
+            try:
+                row = self._line_columns([line])[0]
+            except _LINE_ERRORS:
+                row = self._entry_values(line)
+                if row is None:
+                    continue
+            values.append(row)
+            kept.append(line)
+        return values, kept
+
+    def _line_columns(self, lines: List[str]) -> List[tuple]:
+        ends, starts, servers, services, logids, accts, elapseds, tops = zip(
+            *map(_TX_FIELDS, [line.split("|") for line in lines]))
+        iso = self._iso_column
+        return list(zip(
+            iso(ends), iso(starts), servers, services, logids,
+            map(_tx_int, accts), map(_tx_int, elapseds), tops,
+        ))
+
+    def _iso_column(self, fields: Sequence[str]) -> List[Optional[str]]:
+        """endts/startts column values of ms fields: a column of plain
+        digit runs in one pass, any other field by ``_ms_iso``."""
+        if all(fields):
+            digits = "".join(fields)
+            if digits.isascii() and digits.isdigit():
+                ms = list(map(int, fields))
+                if max(ms) < _EXACT_MS:
+                    get, second = self._iso_prefix.get, self._iso_second
+                    return [(get(m // 1000) or second(m // 1000)) + _MS_SUFFIX[m % 1000]
+                            for m in ms]
+        return list(map(self._ms_iso, fields))
+
+    def _ms_iso(self, field: str) -> Optional[str]:
+        """endts/startts column value: the ISO-8601 Z string of
+        ``datetime.fromtimestamp(js_parse_int(field) / 1000.0, tz=utc)``,
+        None for NaN."""
+        if field.isascii() and field.isdigit():
+            ms = int(field)
+        elif field == "NaN":
+            return None
+        else:
+            ms = js_parse_int(field)
+            if ms != ms:
+                return None
+        if not -_EXACT_MS < ms < _EXACT_MS:
+            return _iso_z(datetime.fromtimestamp(float(ms) / 1000.0, tz=timezone.utc))
+        sec, rem = divmod(int(ms), 1000)
+        return (self._iso_prefix.get(sec) or self._iso_second(sec)) + _MS_SUFFIX[rem]
+
+    def _iso_second(self, sec: int) -> str:
+        """The ISO-8601 prefix of a whole UTC second, cached."""
+        if len(self._iso_prefix) >= _ISO_PREFIX_CACHE:
+            self._iso_prefix.clear()
+        # "...T22:13:20.000Z" less "000Z"
+        prefix = self._iso_prefix[sec] = _iso_z(datetime.fromtimestamp(sec, tz=timezone.utc))[:-4]
+        return prefix
+
+    def _entry_values(self, line: str) -> Optional[tuple]:
+        """The Entry path for one tx line: its insert tuple, or None after
+        logging why it is dropped."""
+        try:
+            entry = self._factory.from_csv(line)
+        except OverflowError:  # a numeric field past float's range
+            entry = None
+        if entry is None:
+            if self.logger:
+                self.logger.info(f"Entry undefined: {line}")
+            return None
+        try:
+            obj = entry.to_postgres()
+        except Exception as e:
+            if self.logger:
+                self.logger.error(f"to_postgres for type:{entry.type} threw an error: {e}")
+            return None
+        return adapt_rows(self.column_sets[entry.type], [obj])[0]
 
     def process_all(self) -> None:
         """Flush everything (processAllBuffers, on shutdown)."""
@@ -422,8 +603,22 @@ class DBWriter:
 
     def save_resume(self, path: str) -> None:
         with self._lock:
-            payload = {t: [self._resume_row(r) for r in b] for t, b in self._buffers.items()}
+            buffers = {t: list(b) for t, b in self._buffers.items()}
+        payload = {t: self._resume_rows(t, b) for t, b in buffers.items()}
         save_resume_file(path, payload, logger=self.logger)
+
+    def _resume_rows(self, etype: str, rows: list) -> List[dict]:
+        """The resume file's rows: a tx line as the dict of its column
+        values (the JSON its Entry's ``to_postgres`` dict gives), a dict
+        row through ``_resume_row``."""
+        cols = self.column_sets[etype].columns
+        out: List[dict] = []
+        for is_line, run in groupby(rows, key=_is_line):
+            if is_line:
+                out += [dict(zip(cols, v)) for v in self._line_values(list(run))[0]]
+            else:
+                out += [self._resume_row(r) for r in run]
+        return out
 
     @classmethod
     def _resume_row(cls, value):

@@ -2,13 +2,23 @@
 
 import math
 
-from apmbackend_tpu.entries import AlertEntry, FullStatEntry, JmxEntry, StatEntry, TxEntry
+import pytest
+
+from apmbackend_tpu.entries import (
+    AlertEntry,
+    EntryFactory,
+    FullStatEntry,
+    JmxEntry,
+    StatEntry,
+    TxEntry,
+)
 from apmbackend_tpu.sinks import (
     DBWriter,
     FakeExecutor,
     SQLiteExecutor,
     column_sets_from_config,
 )
+from apmbackend_tpu.sinks.db import _adapt
 from apmbackend_tpu.utils.counters import DBStats
 
 
@@ -197,3 +207,133 @@ def test_background_timer_thread_flushes():
         time.sleep(0.02)
     assert ex.batches == [("tx", 1)]
     w.close()
+
+
+# -- tx lines: wire line -> insert tuple at flush ------------------------------
+
+class ListLogger:
+    def __init__(self):
+        self.lines = []
+
+    def info(self, msg):
+        self.lines.append(msg)
+
+    error = info
+
+
+def entry_path(line):
+    """What the Entry path makes of a tx line: its insert tuple and no log
+    line, or None and the line it logs."""
+    cols = column_sets_from_config({})["tx"].columns
+    entry = EntryFactory().from_csv(line)
+    if entry is None:
+        return None, f"Entry undefined: {line}"
+    try:
+        obj = entry.to_postgres()
+    except Exception as e:
+        return None, f"to_postgres for type:tx threw an error: {e}"
+    assert tuple(obj) == cols
+    return tuple(_adapt(v) for v in obj.values()), None
+
+
+def tx_line(acct="42", start="1700000000000", end="1700000005123", elapsed="5123", extra=""):
+    return f"tx|srv1|svc|log1|{acct}|{start}|{end}|{elapsed}|Y{extra}"
+
+
+TX_LINES = {
+    "plain": tx_line(),
+    "nan_acctnum": tx_line(acct="NaN"),
+    "nan_elapsed": tx_line(elapsed="NaN"),
+    "nan_start_end": tx_line(start="NaN", end="NaN"),
+    "non_integer_numerics": tx_line(acct="12.7", start=" 1700000000000", end="1700000005123abc",
+                                    elapsed=" 42"),
+    "trailing_garbage": tx_line(acct="7abc", elapsed="+7"),
+    "negative_ms": tx_line(start="-1", end="-86400001", elapsed="-5"),
+    "pre_epoch_ms": tx_line(start="-2208988800000", end="-1"),
+    "ms_000_and_999": tx_line(start="1700000000000", end="1700000000999"),
+    "past_exact_window": tx_line(start="9999999999999", end="-9999999999999"),
+    "past_datetime_range": tx_line(end="999999999999999999"),
+    "too_few_fields": "tx|srv1|svc|log1|42|1700000000000|1700000005123",
+    "extra_trailing_fields": tx_line(extra="|more|fields"),
+    "unicode_digits": tx_line(acct="٤٢", end="١700000005123"),
+    "past_float53": tx_line(acct="12345678901234567", elapsed="9007199254740993"),
+}
+
+
+@pytest.mark.parametrize("line", TX_LINES.values(), ids=TX_LINES.keys())
+def test_tx_line_inserts_the_entry_paths_row(line):
+    row, logged = entry_path(line)
+    fake_log, sqlite_log = ListLogger(), ListLogger()
+    fw, fake, _ = make_writer(limit=100, logger=fake_log)
+    sw, lite, _ = make_writer(limit=100, executor=SQLiteExecutor(":memory:"), logger=sqlite_log)
+    for w in (fw, sw):
+        w.consume_line(line)
+        assert w.buffered_counts()["tx"] == 1
+        w.process_all()
+    if row is None:
+        assert fake.tables.get("tx", []) == []
+        assert fake_log.lines == sqlite_log.lines == [logged]
+        return
+    assert fake.tables["tx"] == [row]
+    assert lite._conn.execute("SELECT * FROM tx").fetchall() == [row]
+    assert fake_log.lines == sqlite_log.lines == []
+    sw.close()
+
+
+def test_mixed_buffer_flushes_in_arrival_order(tmp_path):
+    path = str(tmp_path / "db_buffer.resume")
+    w0, _, _ = make_writer(limit=100)
+    w0.add_entry(tx(1))
+    w0.save_resume(path)
+    w, ex, _ = make_writer(limit=100)
+    w.consume_line(tx(0).to_csv())
+    assert w.load_resume(path)  # restored rows go in front
+    w.consume_line(tx(2).to_csv())
+    w.add_entry(tx(3))
+    w.consume_line(tx(4).to_csv())
+    w.consume_line(tx(5).to_csv())
+    w.process_all()
+    assert [row[4] for row in ex.tables["tx"]] == [f"log{i}" for i in (1, 0, 2, 3, 4, 5)]
+    assert ex.tables["tx"] == [entry_path(tx(i).to_csv())[0] for i in (1, 0, 2, 3, 4, 5)]
+    assert ex.batches == [("tx", 6)]
+
+
+def test_failed_insert_requeues_lines_in_front():
+    log = ListLogger()
+    w, ex, clock = make_writer(limit=100, logger=log)
+    w.consume_line(tx(1).to_csv())
+    w.consume_line(TX_LINES["too_few_fields"])
+    w.consume_line(tx(2).to_csv())
+
+    def fail_as_a_line_arrives(cs, values):
+        w.consume_line(tx(3).to_csv())
+        raise RuntimeError("injected insert failure")
+
+    ex.insert_rows = fail_as_a_line_arrives
+    assert not w.process_buffer("tx")
+    assert w.buffered_counts()["tx"] == 3  # the short line is dropped, once
+    assert log.lines[0] == f"Entry undefined: {TX_LINES['too_few_fields']}"
+    del ex.insert_rows
+    clock.t += 6
+    assert w.process_due() == ["tx"]
+    assert ex.batches == [("tx", 3)]
+    assert [row[4] for row in ex.tables["tx"]] == ["log1", "log2", "log3"]
+    assert len(log.lines) == 2  # the drop, then the failed insert
+
+
+def test_save_resume_of_lines_writes_the_entry_paths_json(tmp_path):
+    lines = [tx(i).to_csv() for i in range(3)] + [
+        line for line in TX_LINES.values() if entry_path(line)[0] is not None]
+    by_entry, _, _ = make_writer(limit=100)
+    by_line, _, _ = make_writer(limit=100)
+    for line in lines:
+        by_entry.add_entry(EntryFactory().from_csv(line))  # dict rows
+        by_line.consume_line(line)
+    jx = JmxEntry(1700000000000, "host1", *range(16))
+    for w in (by_entry, by_line):
+        w.add_entry(jx)
+    by_entry.save_resume(str(tmp_path / "entry.resume"))
+    by_line.save_resume(str(tmp_path / "line.resume"))
+    expected = (tmp_path / "entry.resume").read_bytes()
+    assert (tmp_path / "line.resume").read_bytes() == expected
+    assert by_line.buffered_counts()["tx"] == len(lines)  # a save keeps the buffer

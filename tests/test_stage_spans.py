@@ -3,7 +3,7 @@ accumulates as ``add_busy`` does and lands on the profiler's host plane;
 counters ride the clock into ``snapshot()``, ``/attrib`` and the registry;
 the spans and counters the program keeps at its stage boundaries
 (``parser_sweep``, ``ingest_dispatch``, ``state_save``, ``state_restore``,
-``tick_emit``, ``gc``)."""
+``tick_emit``, ``sink_absorb``, ``gc``)."""
 
 import gc
 import glob
@@ -26,6 +26,7 @@ from apmbackend_tpu.obs.attrib import (
     STAGE_GC,
     STAGE_INGEST_DISPATCH,
     STAGE_PARSER_SWEEP,
+    STAGE_SINK_ABSORB,
     STAGE_STATE_RESTORE,
     STAGE_STATE_SAVE,
     STAGE_TICK_EMIT,
@@ -341,6 +342,32 @@ def test_tick_emit_counts_banded_and_signalled_rows(fresh_plane):
     signals = sum(any(getattr(r, p + "_signal") for p in parts) for r in rows)
     assert banded > 0 and signals > 0
     assert (st["banded"], st["signals"]) == (banded, signals)
+
+
+def test_sink_absorb_counts_rows_and_rows_from_tx_lines(fresh_plane):
+    from apmbackend_tpu.entries import FullStatEntry
+    from apmbackend_tpu.sinks import DBWriter, FakeExecutor
+
+    ex = FakeExecutor()
+    w = DBWriter(ex, {"dbInsertBufferLimit": 100}, start_timer=False)
+    fs = FullStatEntry(1700000000000, "s", "svc", 2.5, 360, *range(15))
+    for i in range(5):
+        w.consume_line(TxEntry("s", "svc", f"log{i}", 42, 1, 2, 1, "Y").to_csv())
+    w.consume_line("tx|s|svc|short")  # dropped at flush: no row
+    w.add_entry(TxEntry("s", "svc", "dict", 42, 1, 2, 1, "Y"))  # a dict row
+    w.consume_line(fs.to_csv())
+    w.consume_line(fs.to_csv())
+    st = fresh_plane.stage_table()[STAGE_SINK_ABSORB]
+    assert (st["rows"], st["lines"], st["events"]) == (0, 0, 0)  # declared
+    w.process_all()
+    st = fresh_plane.stage_table()[STAGE_SINK_ABSORB]
+    assert ex.batches == [("tx", 6), ("stats", 2)]
+    assert (st["rows"], st["lines"], st["events"]) == (8, 5, 2)
+    ex.fail = True
+    w.consume_line(TxEntry("s", "svc", "log5", 42, 1, 2, 1, "Y").to_csv())
+    w.process_all()  # a failed insert counts no row
+    st = fresh_plane.stage_table()[STAGE_SINK_ABSORB]
+    assert (st["rows"], st["lines"], st["events"]) == (8, 5, 3)
 
 
 def test_shared_clock_loses_no_update_across_threads(fresh_plane):
